@@ -7,7 +7,12 @@
 from __future__ import annotations
 
 import argparse
+import os
 import time
+
+from repro.launch.compile_cache import use_compile_cache
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def main() -> None:
@@ -22,6 +27,7 @@ def main() -> None:
                          "runs the one-compile 16-member sweep, cache "
                          "runs the DDQN-vs-classical cacher scoreboard")
     args = ap.parse_args()
+    use_compile_cache(_ROOT)
     only = set(args.only.split(",")) if args.only else None
     episodes = 500 if args.full else 60
 

@@ -32,6 +32,7 @@ for p in (_ROOT, os.path.join(_ROOT, "src")):
         sys.path.insert(0, p)
 
 from repro.core import EnvCfg                      # noqa: E402
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from benchmarks import bench_scenarios             # noqa: E402
 
 PRESETS = {
@@ -96,6 +97,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI-scale sweep (overrides sizes/episodes)")
     args = ap.parse_args()
+    use_compile_cache(_ROOT)
 
     flag_overrides = {k: v for k, v in dict(
         eps_schedule=args.eps_schedule, lr_schedule=args.lr_schedule,

@@ -28,6 +28,7 @@ for p in (_ROOT, os.path.join(_ROOT, "src")):
 
 from repro.core import EnvCfg                      # noqa: E402
 from repro.fleet import FleetCfg                   # noqa: E402
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from benchmarks import bench_fleet                 # noqa: E402
 
 SMOKE_RATE_FLOOR = 1e5      # simulated requests/min, warm tick scan
@@ -73,6 +74,7 @@ def main():
                     help="tiny CI-scale sweep; asserts the sustained twin "
                          f"rate >= {SMOKE_RATE_FLOOR:.0e} requests/min")
     args = ap.parse_args()
+    use_compile_cache(_ROOT)
 
     env = EnvCfg(U=args.users, M=args.models, T=args.frames, K=args.slots)
     fcfg = FleetCfg(ticks_per_slot=args.ticks,

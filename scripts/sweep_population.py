@@ -49,6 +49,8 @@ def main() -> None:
 
     from benchmarks import bench_population
     from repro.core import default_grid
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache(_ROOT)
 
     ups = tuple(int(v) for v in args.updates_per_slot.split(","))
     grid = default_grid(updates_per_slot=ups)

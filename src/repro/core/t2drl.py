@@ -30,9 +30,7 @@ Compiled-path engineering (DESIGN.md §12): scan carries hold only what a
 timescale mutates (agent state, env, carried observation — replay buffers
 are scan constants within a frame), replay writes are batched once per
 frame, epsilon/sigma/LR schedules are precomputed scan inputs, the train
-state is donated through ``run_training``, and on CPU the episode programs
-are compiled with the sequential (non-thunk) XLA runtime, which executes
-these long two-level scans measurably faster.
+state is donated through ``run_training``.
 """
 from __future__ import annotations
 
@@ -43,6 +41,7 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.api_util import shaped_abstractify
 
 # only repro.agents.base (which has no repro.core dependency) is safe to
 # import at module level; the factory dispatch is imported lazily inside
@@ -1107,77 +1106,29 @@ def _episode_batch(ts, cfg: T2DRLCfg, keys, step, *, train: bool,
 
 # -- compiled entry points ----------------------------------------------------
 #
-# On CPU the mostly-sequential episode programs — the single-env scan and
-# the shared-learner lockstep scan — execute measurably faster (~1.15x on
-# the 2-core CI box) under XLA's sequential (non-thunk) runtime, so those
-# entry points are AOT-compiled with that option and cached per (config,
-# train flag, argument structure).  The vmapped independent-learner program
-# (B > 1) is the opposite case — its B stacked per-cell updates benefit
-# from the thunk runtime's scheduling (~2.5x over sequential, measured) —
-# so it keeps the default compile.  run_episode and run_training share the
-# machinery, keeping the B=1 equivalence pin exact; unknown options
-# (future jaxlib) fall back to the default compile, and non-CPU backends
-# use the plain jit path untouched.
-
-_CPU_EPISODE_COMPILER_OPTIONS = {"xla_cpu_use_thunk_runtime": False}
-_AOT_CACHE: dict = {}
-
-
-def _episode_compiler_options(cfg: T2DRLCfg, num_envs: int):
-    """Compiler options for an episode program: sequential runtime for the
-    single-env, shared-learner, and fused independent-learner scans —
-    all are one mostly-sequential batched program — default (thunk) only
-    for the legacy vmapped independent path, whose B interleaved
-    per-cell programs benefit from thunk scheduling (see block comment
-    above; DESIGN.md §13)."""
-    if cfg.policy == "shared" or num_envs == 1:
-        return _CPU_EPISODE_COMPILER_OPTIONS
-    if cfg.policy == "independent" and cfg.independent_impl == "fused":
-        return _CPU_EPISODE_COMPILER_OPTIONS
-    return None
+# run_episode and run_training dispatch through one counted jit call, so
+# every compile reaches the recompile counter (DESIGN.md §15).
 
 
 def _args_signature(tree):
-    try:
-        from jax.api_util import shaped_abstractify
-        leaves, treedef = jax.tree.flatten(tree)
-        return (treedef,) + tuple(shaped_abstractify(l) for l in leaves)
-    except Exception:
-        leaves, treedef = jax.tree.flatten(tree)
-        return (treedef,) + tuple(
-            (jnp.shape(l), jnp.result_type(l)) for l in leaves)
+    leaves, treedef = jax.tree.flatten(tree)
+    return (treedef,) + tuple(shaped_abstractify(l) for l in leaves)
 
 
-def _aot_episode_call(tag, jitted, static_kw, dyn_args, options):
-    """Call ``jitted`` through the AOT cache with the given CPU compiler
-    options; fall back to the plain jit path off-CPU, for ``options=None``,
-    or if the options are rejected (future jaxlib).
-
-    Every compile — AOT cache miss or plain-jit cache growth — is reported
-    to the ``repro.obs.profiling`` recompile counter (DESIGN.md §15).  The
+def _episode_call(tag, jitted, static_kw, dyn_args):
+    """Call ``jitted`` and report a compile (jit cache growth) to the
+    ``repro.obs.profiling`` recompile counter (DESIGN.md §15).  The
     counter tag is namespaced per static config so distinct experiment
     configs don't read as retraces of one another; within one config the
     expected program count is two (full chunk + ragged remainder), and the
     counter warns beyond that."""
     statics = tuple(sorted(static_kw.items()))
     full_tag = f"{tag}:{hash(statics) & 0xFFFFFFFF:08x}"
-    if options is None or jax.default_backend() != "cpu":
-        before = jitted._cache_size()
-        out = jitted(*dyn_args, **static_kw)
-        if jitted._cache_size() > before:
-            record_compile(full_tag, repr(_args_signature(dyn_args)))
-        return out
-    sig = (tag,) + statics + _args_signature(dyn_args)
-    compiled = _AOT_CACHE.get(sig)
-    if compiled is None:
+    before = jitted._cache_size()
+    out = jitted(*dyn_args, **static_kw)
+    if jitted._cache_size() > before:
         record_compile(full_tag, repr(_args_signature(dyn_args)))
-        lowered = jitted.lower(*dyn_args, **static_kw)
-        try:
-            compiled = lowered.compile(compiler_options=options)
-        except Exception:
-            compiled = lowered.compile()
-        _AOT_CACHE[sig] = compiled
-    return compiled(*dyn_args)
+    return out
 
 
 def _run_episode_impl(ts, key, eps, sigma, mods=None, *, cfg: T2DRLCfg,
@@ -1194,10 +1145,9 @@ def run_episode(ts, cfg: T2DRLCfg, key, eps, sigma, *, train: bool = True,
                 mods: Optional[ScenarioSchedule] = None):
     """One episode of Algorithm 1 (single env).  ``mods``: optional
     unbatched ScenarioSchedule (DESIGN.md §9).  Returns (ts, stats)."""
-    return _aot_episode_call("episode", _run_episode_jit,
-                             {"cfg": cfg, "train": train},
-                             (ts, key, eps, sigma, mods),
-                             _episode_compiler_options(cfg, 1))
+    return _episode_call("episode", _run_episode_jit,
+                         {"cfg": cfg, "train": train},
+                         (ts, key, eps, sigma, mods))
 
 
 def _training_xs(cfg: T2DRLCfg, key, ep_idx, B: int, *, train: bool,
@@ -1303,16 +1253,15 @@ def run_training(ts, cfg: T2DRLCfg, key, ep_idx, masks=None, mods=None, *,
     Returns (ts, history) with history leaves of shape (len(ep_idx), B)."""
     B = ts["models"].a1.shape[0]
     pop = _validate_pop(pop, cfg, B, len(ep_idx))
-    return _aot_episode_call("train", _run_training_jit,
-                             {"cfg": cfg, "train": train},
-                             (ts, key, ep_idx, masks, mods, pop),
-                             _episode_compiler_options(cfg, B))
+    return _episode_call("train", _run_training_jit,
+                         {"cfg": cfg, "train": train},
+                         (ts, key, ep_idx, masks, mods, pop))
 
 
 def run_training_sharded(ts, cfg: T2DRLCfg, key, ep_idx, masks=None, *,
                          train: bool = True, pop=None, mesh=None):
     """``run_training`` with the B independent cells sharded across devices
-    via ``jax.experimental.shard_map`` (opt-in, DESIGN.md §13).
+    via ``jax.shard_map`` (opt-in, DESIGN.md §13).
 
     Each device runs the fused episode program on its contiguous slice of
     cells; there is no cross-cell communication (independent learners), so
@@ -1328,7 +1277,6 @@ def run_training_sharded(ts, cfg: T2DRLCfg, key, ep_idx, masks=None, *,
     jax use to expose N devices.  B must divide evenly across the mesh.
     ``mods`` schedules are not supported on this path; ``ts`` is not
     donated."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     if cfg.policy != "independent" or cfg.independent_impl != "fused":
         raise ValueError("run_training_sharded requires policy="
@@ -1349,9 +1297,13 @@ def run_training_sharded(ts, cfg: T2DRLCfg, key, ep_idx, masks=None, *,
     def local(ts_, xs_, masks_):
         return _scan_episodes(ts_, cfg, xs_, train=train, masks=masks_)
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P("cells"), xs_specs, P("cells")),
-                   out_specs=(P("cells"), P(None, "cells")))
+    # check_vma=False: the cells are independent (no collectives); the
+    # check would only demand that constant scan-carry inits (the frame
+    # scan's rho) be cast as varying over "cells"
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P("cells"), xs_specs, P("cells")),
+                       out_specs=(P("cells"), P(None, "cells")),
+                       check_vma=False)
     return jax.jit(fn)(ts, xs, masks)
 
 

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from .ddpm_step import ddpm_step_2d
 from .flash_attention import flash_attention_bhld
-from .ssd_scan import ssd_scan_blhp
+from .ssd_scan import ssd_scan_bhlp
 
 
 def _interpret() -> bool:
@@ -58,11 +58,14 @@ def ssd_scan(x, dt, A, Bm, Cm, D, *, chunk: int = 128):
         dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
         Bm = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0), (0, 0)))
         Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    y, s = ssd_scan_blhp(x.astype(jnp.float32), dt.astype(jnp.float32),
-                         A.astype(jnp.float32), Bm.astype(jnp.float32),
-                         Cm.astype(jnp.float32), D.astype(jnp.float32),
-                         chunk=Q, interpret=_interpret())
-    return y[:, :L], s
+    # head-major for the kernel: (B, L, H, P) -> (B, H, L, P), dt as a
+    # (B, H, L, 1) column
+    f32 = lambda a: a.astype(jnp.float32)
+    y, s = ssd_scan_bhlp(
+        f32(x).transpose(0, 2, 1, 3), f32(dt).transpose(0, 2, 1)[..., None],
+        f32(A), f32(Bm).transpose(0, 2, 1, 3), f32(Cm).transpose(0, 2, 1, 3),
+        f32(D), chunk=Q, interpret=_interpret())
+    return y.transpose(0, 2, 1, 3)[:, :L], s
 
 
 @jax.jit

@@ -8,7 +8,15 @@ init, and smoke tests must keep seeing 1 device.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+
+def _auto_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with ``Auto`` axes: the model code places
+    activations with ``with_sharding_constraint`` (``repro.nn.sharding``),
+    which accepts only Auto axes, whereas ``make_mesh`` defaults to
+    Explicit ones."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -16,12 +24,12 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     Multi-pod: (2, 16, 16) = 512 chips, axes (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh() -> Mesh:
     """1×1 mesh over the local device — smoke tests / CPU examples."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_cells_mesh(n_devices: int | None = None) -> Mesh:
@@ -32,7 +40,7 @@ def make_cells_mesh(n_devices: int | None = None) -> Mesh:
     devices are obtained with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``."""
     n = len(jax.devices()) if n_devices is None else n_devices
-    return jax.make_mesh((n,), ("cells",))
+    return _auto_mesh((n,), ("cells",))
 
 
 def batch_axes(mesh: Mesh):

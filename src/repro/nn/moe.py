@@ -137,16 +137,8 @@ def moe_apply_shardmap(p, cfg: MoECfg, x, *, compute_dtype=jnp.bfloat16):
     psum of the (T_local, D) activations over the model axis — vs the
     GSPMD path's full (E·cap, D) buffer all-reduces."""
     import functools
-    import inspect
     from jax.sharding import PartitionSpec as P
     from .sharding import batch_axes, current_mesh
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:        # pre-0.6 jax: experimental namespace
-        from jax.experimental.shard_map import shard_map
-    # the replication-check kwarg was renamed check_rep -> check_vma
-    check_kw = ("check_vma" if "check_vma"
-                in inspect.signature(shard_map).parameters else "check_rep")
     mesh = current_mesh()
     assert mesh is not None and "model" in mesh.axis_names
     ba = batch_axes()
@@ -155,13 +147,13 @@ def moe_apply_shardmap(p, cfg: MoECfg, x, *, compute_dtype=jnp.bfloat16):
     body = functools.partial(
         _local_dispatch_combine, cfg=cfg, compute_dtype=compute_dtype,
         model_axis="model", all_axes=all_axes)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None),
                   P(lead if ba else None, None, None)),
         out_specs=(P(lead if ba else None, None, None), P()),
-        **{check_kw: False},
+        check_vma=False,
     )(p["router"]["w"], p["up"], p["gate"], p["down"], x)
     if "shared" in p:
         y = y + mlp_apply(p["shared"],

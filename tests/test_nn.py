@@ -159,6 +159,7 @@ def test_moe_shardmap_matches_gspmd_on_host_mesh():
     path (exercised on a 1x1 host mesh; the multi-device equivalence is
     covered by the dry-run and a calibration script)."""
     import dataclasses
+    from repro.launch.mesh import make_host_mesh
     from repro.nn import sharding as shlib
     cfg = MoECfg(32, 64, n_experts=4, top_k=2, n_shared=1,
                  capacity_factor=8.0)
@@ -166,7 +167,7 @@ def test_moe_shardmap_matches_gspmd_on_host_mesh():
     p = moe_init(KEY, cfg)
     x = jax.random.normal(KEY, (2, 8, 32))
     y_ref, _ = moe_apply(p, cfg, x, compute_dtype=jnp.float32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     with shlib.use_mesh(mesh), mesh:
         y_sm, _ = jax.jit(lambda p, x: moe_apply(p, cfg_sm, x,
                                                  compute_dtype=jnp.float32))(p, x)
