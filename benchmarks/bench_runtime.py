@@ -55,7 +55,7 @@ from repro.core import (EnvCfg, GACfg, T2DRLCfg, actor_act, env_reset,
                         ga_allocate, make_actor_schedule, make_models,
                         observe, run_training, run_training_sharded,
                         t2drl_init, t2drl_init_batch)
-from repro.obs import ObsCfg, profiler_trace
+from repro.obs import ObsCfg
 from .common import OUT_DIR, save_json
 
 # Pre-refactor (PR 3, commit ae1b38e) shared-learner B=8 throughput on the
@@ -299,19 +299,17 @@ def run_breakdown(num_envs=(1, 8), episodes: int = 4, reps: int = 3,
 
 
 def run_obs_overhead(episodes: int = 4, reps: int = 3, seed: int = 0,
-                     trace_dir: str | None = None, verbose=True) -> dict:
+                     verbose=True) -> dict:
     """Telemetry cost: the fully-tapped in-scan diagnostics program
     (``obs=ObsCfg(enabled=True)``, DESIGN.md §15) vs the identical
     telemetry-off training run, at B=1 on the paper workload.  The ISSUE-8
-    acceptance bound is <5% wall-clock overhead.  ``trace_dir`` wraps the
-    telemetry-on measurement in a ``jax.profiler`` trace.
+    acceptance bound is <5% wall-clock overhead.
 
     Writes an ``obs_overhead`` section into runtime.json."""
     base = _throughput_cfg("independent")            # obs off by default
     tapped = dataclasses.replace(base, obs=ObsCfg(enabled=True))
     t_off, off_times, c_off = _measure(base, 1, episodes, reps, seed)
-    with profiler_trace(trace_dir):
-        t_on, on_times, c_on = _measure(tapped, 1, episodes, reps, seed)
+    t_on, on_times, c_on = _measure(tapped, 1, episodes, reps, seed)
     overhead = t_on / t_off - 1.0
     out = {"obs_overhead": {
         "episodes": episodes, "reps": reps,
@@ -325,8 +323,6 @@ def run_obs_overhead(episodes: int = 4, reps: int = 3, seed: int = 0,
     if verbose:
         print(f"obs overhead: off {t_off:.2f}s, on {t_on:.2f}s -> "
               f"{100 * overhead:+.1f}% (acceptance < +5%)", flush=True)
-        if trace_dir:
-            print(f"profiler trace written under {trace_dir}", flush=True)
     _merge_runtime_json(out)
     return out
 
@@ -438,16 +434,12 @@ def main():
     ap.add_argument("--obs-overhead", action="store_true",
                     help="telemetry-on vs telemetry-off wall-clock cost of "
                          "the in-scan diagnostics (DESIGN.md §15)")
-    ap.add_argument("--trace-dir", default=None,
-                    help="with --obs-overhead: write a jax.profiler trace "
-                         "of the telemetry-on run under this directory")
     args = ap.parse_args()
     if args.smoke:
         run_smoke(floor=args.floor)
         return
     if args.obs_overhead:
-        run_obs_overhead(episodes=args.episodes, reps=args.reps,
-                         trace_dir=args.trace_dir)
+        run_obs_overhead(episodes=args.episodes, reps=args.reps)
         return
     if args.breakdown:
         run_breakdown(tuple(args.num_envs), episodes=args.episodes)

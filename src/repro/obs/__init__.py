@@ -3,8 +3,8 @@
 See DESIGN.md §15 for the telemetry contract (tap points, schema version,
 off-by-default guarantee).
 """
-from .profiling import (compile_count, compile_events, profiler_trace,
-                        record_compile, reset_compiles, stage)
+from .profiling import (compile_count, compile_events, record_compile,
+                        reset_compiles)
 from .taps import ObsCfg, broadcast_diag, combine_updates, reduce_update_diag
 from .writer import (SCHEMA, MetricWriter, cfg_hash, progress_line,
                      run_manifest, to_jsonable, validate_jsonl,
@@ -15,5 +15,4 @@ __all__ = [
     "SCHEMA", "MetricWriter", "cfg_hash", "progress_line", "run_manifest",
     "to_jsonable", "validate_jsonl", "validate_record",
     "compile_count", "compile_events", "record_compile", "reset_compiles",
-    "stage", "profiler_trace",
 ]

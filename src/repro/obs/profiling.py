@@ -1,18 +1,13 @@
-"""Profiling hooks (DESIGN.md §15): stage timers and a recompile counter.
+"""Profiling hooks (DESIGN.md §15): a recompile counter.
 
 The recompile counter is fed by the episode-dispatch layer in
 ``core.t2drl`` — every fresh XLA compile registers a :func:`record_compile`
 event, so silent retraces (a ragged final ``log_every`` chunk, a config
 leaking a traced value into a static field) show up as a count, not a
-mystery slowdown.  :func:`stage` wraps host-side phases in wall-clock
-timers (emitting ``profile`` records through a ``MetricWriter`` when one
-is attached), and :func:`profiler_trace` gates a ``jax.profiler`` trace
-behind an opt-in flag for the benchmarks.
+mystery slowdown.
 """
 from __future__ import annotations
 
-import contextlib
-import time
 import warnings
 
 # Compile-event log: (tag, signature) per fresh XLA compile, appended by
@@ -57,32 +52,3 @@ def reset_compiles() -> None:
     _COMPILE_EVENTS.clear()
     _WARNED_TAGS.clear()
 
-
-@contextlib.contextmanager
-def stage(name: str, writer=None, **fields):
-    """Wall-clock a host-side stage; emits a ``profile`` record when a
-    ``MetricWriter`` is attached.  The yielded dict is live — callers can
-    add fields (e.g. ``info["compile_s"] = ...`` for the compile/execute
-    split) before the record is written on exit."""
-    info = dict(fields)
-    t0 = time.perf_counter()
-    try:
-        yield info
-    finally:
-        wall = time.perf_counter() - t0
-        info["wall_s"] = wall
-        if writer is not None:
-            writer.write("profile", stage=name, **info)
-
-
-@contextlib.contextmanager
-def profiler_trace(trace_dir=None):
-    """Opt-in ``jax.profiler`` trace: active only when ``trace_dir`` is a
-    path, a transparent no-op otherwise (so benchmark code can wrap its
-    hot section unconditionally)."""
-    if not trace_dir:
-        yield
-        return
-    import jax
-    with jax.profiler.trace(str(trace_dir)):
-        yield

@@ -34,7 +34,6 @@ REQUIRED_FIELDS = {
     "fleet_frame": ("frame", "p50_s", "p95_s", "p99_s", "drop_rate",
                     "slo_viol_rate", "mean_backlog_s"),
     "fleet_summary": ("metrics",),
-    "profile": ("stage", "wall_s"),
 }
 
 

@@ -1,6 +1,7 @@
 """Telemetry subsystem (DESIGN.md §15): off-by-default bit-identity,
 in-scan learner diagnostics, JSONL schema validation, run manifests,
-the recompile counter, and the ragged-final-chunk compile pin."""
+the recompile counter, the ragged-final-chunk compile pin, and the
+runtime's host events that the benchmark reads per decision."""
 import dataclasses
 import json
 import warnings
@@ -9,10 +10,11 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import EnvCfg, T2DRLCfg, t2drl_init, train_t2drl
+from repro.core import (EnvCfg, EnvState, T2DRLCfg, env_reset, export_policy,
+                        greedy_slot_action, t2drl_init, train_t2drl)
 from repro.fleet import FleetCfg, simulate_fleet
 from repro.obs import (MetricWriter, ObsCfg, compile_events, progress_line,
-                       reset_compiles, run_manifest, stage, validate_jsonl,
+                       reset_compiles, run_manifest, validate_jsonl,
                        validate_record)
 
 # Small enough for CI but busy enough that both learners actually update:
@@ -139,8 +141,8 @@ def test_ragged_chunk_two_programs_and_bit_identical():
 # -- schema validation --------------------------------------------------------
 
 def test_validate_record_negatives():
-    ok = {"schema": "repro-obs/1", "kind": "profile", "stage": "x",
-          "wall_s": 0.1}
+    ok = {"schema": "repro-obs/1", "kind": "train_chunk", "episode": 2,
+          "episodes": 2, "wall_s": 0.1, "stats": {}}
     validate_record(ok)
     with pytest.raises(ValueError, match="unknown schema"):
         validate_record(dict(ok, schema="repro-obs/999"))
@@ -204,17 +206,44 @@ def test_progress_line_matches_legacy_format():
         "ep    7 reward    -12.35 hit 0.500 G    3.20")
 
 
-def test_stage_timer_emits_profile_record(tmp_path):
-    path = str(tmp_path / "prof.jsonl")
-    with MetricWriter(path) as w:
-        w.ensure_manifest()
-        with stage("compile", writer=w, program="episode") as info:
-            info["compile_s"] = 0.25
-    assert validate_jsonl(path) == 2
-    rec = [json.loads(l) for l in open(path)][1]
-    assert rec["kind"] == "profile" and rec["stage"] == "compile"
-    assert rec["wall_s"] >= 0.0 and rec["compile_s"] == 0.25
-    assert rec["program"] == "episode"
+# -- runtime host events per decision ----------------------------------------
+
+# A greedy decision reads four slot-state leaves (h, req, d_in, rho) and
+# the reverse-chain key; jit drops the leaves it does not read, so each
+# call sent from the host moves five arrays to the device.
+DECISION_HOST_ARGS = 5
+
+
+def test_decision_runtime_host_events(tmp_path):
+    """The runtime's own host events per greedy decision, the names the
+    benchmark's host-dispatch metrics read (PERF.md): one jitted call,
+    one ``DevicePut`` per kept host argument, and two copy-backs (b, xi)."""
+    from jax.profiler import ProfileData
+    cfg = T2DRLCfg(env=EnvCfg(U=4, M=4, T=2, K=2), L=2, warmup=5)
+    ts = t2drl_init(jax.random.PRNGKey(0), cfg)
+    policy, models = export_policy(ts, cfg), ts["models"]
+    env = EnvState(*jax.device_get(env_reset(jax.random.PRNGKey(1), cfg.env)))
+    key = np.asarray(jax.random.PRNGKey(2))
+    fn = jax.jit(greedy_slot_action, static_argnames="cfg")
+    jax.device_get(fn(policy, cfg, env, models, key))       # compile first
+    calls = 3
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(calls):
+            jax.device_get(fn(policy, cfg, env, models, key))
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for plane in ProfileData.from_file(str(path)).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events]
+    count = lambda name: sum(n == name for n, _, _ in events)
+    pjit = [(s, e) for n, s, e in events
+            if n == "PjitFunction(greedy_slot_action)"]
+    # the runtime nests its dispatch events; count the outermost per call
+    outer = [(s, e) for s, e in pjit
+             if not any(a <= s and e <= b and (a, b) != (s, e) for a, b in pjit)]
+    assert len(outer) == calls
+    assert count("DevicePut") == calls * DECISION_HOST_ARGS
+    assert count("np.asarray(jax.Array)") == calls * 2
 
 
 # -- end-to-end run logs ------------------------------------------------------
