@@ -204,17 +204,15 @@ def phase_decide(ts, cfg: T2DRLCfg, *, calls: int, seed: int):
             decide(policy, cfg, env, models, keys[0]))
     t0 = time.perf_counter()
     for k in keys:
-        b, xi = jax.block_until_ready(decide(policy, cfg, env, models, k))
-        b, xi = np.asarray(b), np.asarray(xi)
+        b, xi = np.asarray(decide(policy, cfg, env, models, k))
         assert np.all(np.isfinite(b)) and np.all(np.isfinite(xi))
         assert np.all(b >= 0) and abs(b.sum() - 1.0) < 1e-4
         assert np.all(xi >= 0) and xi.sum() <= 1.0 + 1e-4
     steady_s = (time.perf_counter() - t0) / calls
     with jax.disable_jit():
         eager = greedy_slot_action(policy, cfg, env, models, keys[0])
-    for a, e in zip(first, eager):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(e),
-                                   rtol=1e-3, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(first), np.asarray(eager),
+                               rtol=1e-3, atol=1e-4)
     _report("decide", f"greedy_slot_action x{calls} (steady per call)",
             clock.seconds, steady_s)
 

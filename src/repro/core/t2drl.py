@@ -1584,12 +1584,16 @@ def greedy_slot_action(policy, cfg: T2DRLCfg, env: EnvState,
                        models: ModelParams, key, mask=None):
     """Greedy (no exploration noise) per-slot allocation for any allocator.
 
-    Returns the amended ``(b, xi)`` exactly as the training-time slot step
-    would under ``sigma = 0``; ``key`` drives the diffusion actor's reverse
-    chain (D3PG) or the GA (SCHRS)."""
+    Returns one ``(2, U)`` float32 array: row 0 is the bandwidth shares
+    ``b``, row 1 the compute shares ``xi``, amended exactly as the
+    training-time slot step would under ``sigma = 0``; ``key`` drives the
+    diffusion actor's reverse chain (D3PG) or the GA (SCHRS).  One array,
+    not the tuple ``(b, xi)``: a jitted call then allocates and copies back
+    one device buffer per decision (DESIGN.md §11).  Callers unpack it as
+    ``b, xi = greedy_slot_action(...)``."""
     alloc, _ = _agents(cfg)
     s = observe(env, cfg.env, models, mask) if alloc.learns else None
-    return alloc.greedy(policy, SlotObs(s, env, models, mask), key)
+    return jnp.stack(alloc.greedy(policy, SlotObs(s, env, models, mask), key))
 
 
 def greedy_frame_cache(policy, cfg: T2DRLCfg, models: ModelParams,

@@ -2,6 +2,7 @@
 conservation, histogram quantiles, cloud-fallback semantics, scenario
 traffic scaling, and checkpointed policy deployment bit-identity."""
 import dataclasses
+import re
 
 import jax
 import numpy as np
@@ -194,6 +195,36 @@ def test_greedy_entry_points_match_training_primitives(ts_t2drl):
     np.testing.assert_array_equal(
         np.asarray(greedy_frame_cache({}, RCARS, models, env.gamma_idx, ka)),
         np.asarray(random_cache(ka, models, ENV)))
+
+
+@pytest.mark.parametrize("allocator", ["d3pg", "rcars", "schrs"])
+def test_greedy_slot_action_returns_one_buffer(ts_t2drl, allocator):
+    """The public greedy entry returns ``(b, xi)`` as the rows of one
+    ``(2, U)`` array, bitwise the allocator's own ``greedy`` tuple, and
+    its jitted executable has one non-tuple result (DESIGN.md §11): one
+    output buffer and one copy-back per decision."""
+    from repro.agents import make_allocator
+    from repro.agents.base import SlotObs
+    from repro.core import greedy_slot_action, observe
+    from repro.core.env import env_reset
+    cfg = {"d3pg": CFG, "rcars": RCARS,
+           "schrs": dataclasses.replace(RCARS, allocator="schrs",
+                                        cacher="static")}[allocator]
+    pol = export_policy(ts_t2drl, CFG) if allocator == "d3pg" else {}
+    models = ts_t2drl["models"]
+    env = env_reset(jax.random.PRNGKey(7), ENV)
+    ka = jax.random.PRNGKey(8)
+    out = greedy_slot_action(pol, cfg, env, models, ka)
+    assert out.shape == (2, ENV.U) and out.dtype == np.float32
+    alloc = make_allocator(cfg.allocator, cfg.env, cfg.d3pg_cfg(), cfg.ga)
+    s = observe(env, ENV, models, None) if alloc.learns else None
+    b_ref, xi_ref = alloc.greedy(pol, SlotObs(s, env, models, None), ka)
+    np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(b_ref))
+    np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(xi_ref))
+    hlo = jax.jit(greedy_slot_action, static_argnames="cfg").lower(
+        pol, cfg, env, models, ka).compile().as_text()
+    (result,) = re.findall(r"^ENTRY .*\) -> (.+) \{$", hlo, re.MULTILINE)
+    assert result == f"f32[2,{ENV.U}]"
 
 
 def test_unregistered_namedtuple_raises_clear_error(tmp_path):
