@@ -14,6 +14,15 @@ comparison deciding ``correct`` catches them (``chipbench/tests`` and
   it (scaled by 1.01).
 - ``cache``: each frame's cache action is altered where the cacher
   produces it (the next action id).
+- ``twin_cache``: each frame's cache vector in the fleet twin is rolled by
+  one model where the twin takes it.
+- ``frame_cache``: the greedy cache vector of each popularity state is
+  altered where the program produces it (its last model's bit flipped),
+  for every caller: the twin and whoever reads the vector.
+- ``twin_mix``: the twin's arrival mix is uniform over the models, not
+  the popularity state's Zipf mix.
+- ``twin_service``: the generation delays the twin reads from each slot
+  are halved.
 """
 from __future__ import annotations
 
@@ -26,9 +35,11 @@ from chipbench import program  # noqa: F401  (puts the program on the path)
 
 import repro.agents.allocators as allocators  # noqa: E402
 import repro.agents.cachers as cachers  # noqa: E402
+import repro.core as core  # noqa: E402
 import repro.core.d3pg as d3pg  # noqa: E402
 import repro.core.ddqn as ddqn  # noqa: E402
 import repro.core.t2drl as t2drl  # noqa: E402
+import repro.fleet.twin as twin  # noqa: E402
 
 
 def _frozen(orig):
@@ -64,6 +75,33 @@ def _cache(orig, n_actions):
     return act
 
 
+def _roll_cache(orig):
+    def cache(*a, **kw):
+        return jnp.roll(orig(*a, **kw), 1, axis=-1)
+    return cache
+
+
+def _flip_last(orig):
+    def cache(*a, **kw):
+        rho = orig(*a, **kw)
+        return rho.at[..., -1].set(1 - rho[..., -1])
+    return cache
+
+
+def _uniform_mix(orig):
+    def mix(gamma_idx, cfg):
+        p = orig(gamma_idx, cfg)
+        return jnp.full_like(p, 1.0 / p.shape[-1])
+    return mix
+
+
+def _half_service(orig):
+    def step(*a, **kw):
+        env, r, m = orig(*a, **kw)
+        return env, r, {**m, "delay_gt": 0.5 * m["delay_gt"]}
+    return step
+
+
 def _targets(name, n_actions):
     if name == "frozen":
         return [(d3pg, "adam_update_stacked", _frozen),
@@ -81,10 +119,20 @@ def _targets(name, n_actions):
     if name == "cache":
         return [(cachers, "ddqn_act_stacked",
                  lambda f: _cache(f, n_actions))]
+    if name == "twin_cache":
+        return [(twin, "greedy_frame_cache", _roll_cache)]
+    if name == "frame_cache":
+        return [(mod, "greedy_frame_cache", _flip_last)
+                for mod in (t2drl, core, twin)]
+    if name == "twin_mix":
+        return [(twin, "_zipf_mix", _uniform_mix)]
+    if name == "twin_service":
+        return [(twin, "env_step_slot", _half_service)]
     raise ValueError(f"unknown fault {name!r}")
 
 
-FAULTS = ("frozen", "frozen_ddqn", "half_batch", "action", "reward", "cache")
+FAULTS = ("frozen", "frozen_ddqn", "half_batch", "action", "reward", "cache",
+          "twin_cache", "frame_cache", "twin_mix", "twin_service")
 
 
 @contextlib.contextmanager

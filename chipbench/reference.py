@@ -234,11 +234,19 @@ def _positions(key, lam, c):
     return jnp.where(lam == 0, uni, jnp.where(lam == 1, conc, bnd))
 
 
+# The smallest positive Rayleigh power a float32 uniform u in [0, 1) can
+# give, -log1p(-u) at u = 2^-23.  At u = 0 the draw is exactly 0, h is 0 and
+# every rate and delay of that user is infinite; the reference takes this
+# draw there instead, and no other draw changes.
+SMALLEST_FADE = -math.log1p(-2.0 ** -23)
+
+
 def _gain(key, pos, c):
     bs = jnp.array([c["area"] / 2, c["area"] / 2])
     dis_km = jnp.maximum(jnp.linalg.norm(pos - bs, axis=-1), 1.0) / 1000.0
     g = 10.0 ** ((-128.1 - 37.6 * jnp.log10(dis_km)) / 10.0)
-    return g * jax.random.exponential(key, (pos.shape[0],))
+    fade = jax.random.exponential(key, (pos.shape[0],))
+    return g * jnp.maximum(fade, jnp.float32(SMALLEST_FADE))
 
 
 def _draw_slot(key, env, c, new_lambda=True):
@@ -291,7 +299,8 @@ def slot_metrics(env, c, models, b, xi):
     d_gt = jnp.where(cached > 0, b1 * steps + b2, b1 * a3 + b2)
     d_tl = d_up + d_dw + d_gt
     G = c["alpha"] * d_tl + (1.0 - c["alpha"]) * q
-    return {"G": G, "d_tl": d_tl, "quality": q, "cached": cached}
+    return {"G": G, "d_tl": d_tl, "quality": q, "cached": cached,
+            "d_up": d_up, "d_dw": d_dw, "d_gt": d_gt}
 
 
 def env_step(env, c, models, b, xi):
