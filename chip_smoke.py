@@ -48,7 +48,6 @@ from repro.launch.mesh import make_cells_mesh                # noqa: E402
 from repro.models.lm import lm_forward, lm_init              # noqa: E402
 from repro.scenarios import build_scenario                   # noqa: E402
 from repro.serving import Engine, ServeCfg                   # noqa: E402
-from repro.serving.engine import _bucket                     # noqa: E402
 
 # gitignored: the B=8 checkpoint holds every cell's replay buffers
 DEFAULT_OUT = os.path.join(_ROOT, ".chip_smoke")
@@ -223,10 +222,7 @@ def phase_gateway(lm_cfg, *, requests: int, prompt_len: int, new_tokens: int,
     Asserts every request returns its prefill token plus ``new_tokens``
     decoded ones, that a repeat gives the same tokens, and that each token
     is among the ``_TOP_K`` of a teacher-forced ``lm_forward`` over the
-    prompt and the tokens before it.  ``prompt_len`` is a prefill bucket
-    (a power of two, at least 8), so the engine pads no prompt."""
-    if _bucket(prompt_len) != prompt_len:
-        raise ValueError(f"prompt_len={prompt_len} is not a prefill bucket")
+    prompt and the tokens before it."""
     params = jax.jit(lambda k: lm_init(k, lm_cfg))(jax.random.PRNGKey(seed))
     rng = np.random.default_rng(seed)
     prompts = rng.integers(0, lm_cfg.vocab, (requests, prompt_len),

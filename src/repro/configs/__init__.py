@@ -63,6 +63,8 @@ _ALIASES.update({
     "zamba2-7b": "zamba2_7b", "deepseek-v2-236b": "deepseek_v2_236b",
     "mamba2-130m": "mamba2_130m", "whisper-small": "whisper_small",
     "internvl2-2b": "internvl2_2b", "qwen3-4b": "qwen3_4b",
+    # served by the gateway's Engine, outside the 10 x 4 dry-run matrix
+    "deepseek-v2-lite": "deepseek_v2_lite",
 })
 
 

@@ -9,6 +9,7 @@ from repro.nn.attention import AttnCfg
 from repro.nn.mla import MLACfg
 from repro.nn.mlp import MLPCfg
 from repro.nn.moe import MoECfg
+from repro.nn.rotary import YaRN
 from repro.nn.ssm import SSMCfg
 
 
@@ -39,17 +40,20 @@ def deepseek_lm(name: str, *, layers: int, dense_layers: int, d_model: int,
                 qk_nope_dim: int = 128, qk_rope_dim: int = 64,
                 v_head_dim: int = 128, mtp: bool = False,
                 window: Optional[int] = None, remat: bool = False,
-                capacity_factor: float = 1.25) -> LMCfg:
+                capacity_factor: float = 1.25,
+                yarn: Optional[YaRN] = None, norm_topk_prob: bool = True,
+                held: Optional[Tuple[int, int]] = None) -> LMCfg:
     mla = MLACfg(d_model, n_heads, q_lora_rank=q_lora_rank,
                  kv_lora_rank=kv_lora_rank, qk_nope_dim=qk_nope_dim,
                  qk_rope_dim=qk_rope_dim, v_head_dim=v_head_dim,
-                 window=window)
+                 window=window, yarn=yarn)
     dense_blk = BlockCfg(d_model=d_model, mixer="mla", ffn="mlp", mla=mla,
                          mlp=MLPCfg(d_model, dense_d_ff))
     moe_blk = BlockCfg(d_model=d_model, mixer="mla", ffn="moe", mla=mla,
                        moe=MoECfg(d_model, moe_d_ff, n_experts=n_experts,
                                   top_k=top_k, n_shared=n_shared,
-                                  capacity_factor=capacity_factor))
+                                  capacity_factor=capacity_factor,
+                                  norm_topk_prob=norm_topk_prob, held=held))
     return LMCfg(name=name, vocab=vocab, d_model=d_model,
                  groups=(GroupCfg((dense_blk,), dense_layers),
                          GroupCfg((moe_blk,), layers - dense_layers)),

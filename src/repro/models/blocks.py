@@ -17,10 +17,12 @@ from jax.sharding import PartitionSpec as P
 from repro.nn import core
 from repro.nn.attention import (AttnCfg, attn_decode, attn_forward, attn_init,
                                 attn_spec, init_kv_cache, kv_cache_spec)
-from repro.nn.mla import (MLACfg, init_mla_cache, mla_cache_spec, mla_decode,
-                          mla_forward, mla_init, mla_spec)
+from repro.nn.mla import (MLACfg, init_mla_cache, mla_cache_entry,
+                          mla_cache_spec, mla_decode, mla_forward, mla_init,
+                          mla_spec)
 from repro.nn.mlp import MLPCfg, mlp_apply, mlp_init, mlp_spec
-from repro.nn.moe import MoECfg, moe_apply, moe_init, moe_spec
+from repro.nn.moe import (MoECfg, moe_apply, moe_decode, moe_init,
+                          moe_prefill, moe_spec)
 from repro.nn.ssm import (SSMCfg, init_ssm_state, ssm_decode, ssm_forward,
                           ssm_init, ssm_spec, ssm_state_spec)
 from repro.nn.sharding import batch_spec, constrain
@@ -190,8 +192,10 @@ def block_cache_spec(cfg: BlockCfg, *, seq_shard: Optional[str] = None):
 
 def block_prefill(p, cfg: BlockCfg, x, cache, *, positions=None, enc=None,
                   impl: str = "xla", compute_dtype=jnp.bfloat16):
-    """Full-sequence forward that also fills the cache at positions [0, L)."""
-    aux = jnp.float32(0.0)
+    """Full-sequence forward that also fills the cache at positions [0, L).
+    Returns (x, cache, route): ``route`` is the MoE's ``{"ids",
+    "dropped"}`` (``moe_prefill``), empty for a block without one."""
+    route = {}
     new = dict(cache)
     if cfg.mixer == "attn":
         y, (k, v) = attn_forward(p["mixer"], cfg.attn,
@@ -212,14 +216,9 @@ def block_prefill(p, cfg: BlockCfg, x, cache, *, positions=None, enc=None,
                                         compute_dtype=compute_dtype,
                                         return_kv=True)
         x = x + y
-        new["mixer"] = {
-            "c_kv": jax.lax.dynamic_update_slice_in_dim(
-                cache["mixer"]["c_kv"],
-                c_kv.astype(cache["mixer"]["c_kv"].dtype), 0, axis=1),
-            "k_rope": jax.lax.dynamic_update_slice_in_dim(
-                cache["mixer"]["k_rope"],
-                k_rope.astype(cache["mixer"]["k_rope"].dtype), 0, axis=1),
-        }
+        kv = cache["mixer"]["kv"]
+        new["mixer"] = {"kv": jax.lax.dynamic_update_slice_in_dim(
+            kv, mla_cache_entry(cfg.mla, c_kv, k_rope).astype(kv.dtype), 0, axis=1)}
     elif cfg.mixer == "ssm":
         y, st = ssm_forward(p["mixer"], cfg.ssm,
                             _norm_apply(cfg.norm, p["norm1"], x),
@@ -241,18 +240,22 @@ def block_prefill(p, cfg: BlockCfg, x, cache, *, positions=None, enc=None,
                           _norm_apply(cfg.norm, p["norm2"], x),
                           compute_dtype=compute_dtype)
     elif cfg.ffn == "moe":
-        y, a = moe_apply(p["ffn"], cfg.moe,
-                         _norm_apply(cfg.norm, p["norm2"], x),
-                         compute_dtype=compute_dtype)
+        y, route = moe_prefill(p["ffn"], cfg.moe,
+                               _norm_apply(cfg.norm, p["norm2"], x),
+                               compute_dtype=compute_dtype)
         x = x + y
-        aux = aux + a
     x = constrain(x, batch_spec(None, None))
-    return x, new, aux
+    return x, new, route
 
 
 def block_decode(p, cfg: BlockCfg, x, cache, pos, *,
                  compute_dtype=jnp.bfloat16):
-    """One-token step.  x: (B,1,D); pos: scalar int32."""
+    """One-token step.  x: (B,1,D); pos: int32, a scalar or one per row.
+    Returns (x, new, route): ``new["mixer"]`` is the new token's cache row
+    for an attention mixer (the cache is only read; the caller writes the
+    row), the whole new state for a recurrent one; ``route`` as
+    ``block_prefill``'s."""
+    route = {}
     new = dict(cache)
     if cfg.mixer == "attn":
         y, new["mixer"] = attn_decode(p["mixer"], cfg.attn,
@@ -282,8 +285,8 @@ def block_decode(p, cfg: BlockCfg, x, cache, pos, *,
                           _norm_apply(cfg.norm, p["norm2"], x),
                           compute_dtype=compute_dtype)
     elif cfg.ffn == "moe":
-        y, _ = moe_apply(p["ffn"], cfg.moe,
-                         _norm_apply(cfg.norm, p["norm2"], x),
-                         compute_dtype=compute_dtype)
+        y, route = moe_decode(p["ffn"], cfg.moe,
+                              _norm_apply(cfg.norm, p["norm2"], x),
+                              compute_dtype=compute_dtype)
         x = x + y
-    return x, new
+    return x, new, route

@@ -324,94 +324,162 @@ def lm_cache_spec(cfg: LMCfg, *, seq_shard: Optional[str] = None):
     return out
 
 
+def _stack_routes(routes):
+    """One scanned repeat's MoE routes, in layer order: ``{"ids": (m, B,
+    L, K), "dropped": ()}`` for its m MoE blocks, ``{}`` for none."""
+    if not routes:
+        return {}
+    return {"ids": jnp.stack([r["ids"] for r in routes]),
+            "dropped": sum(r["dropped"] for r in routes)}
+
+
+def _merge_routes(groups):
+    """The routes of every group's scan -> ``{"ids": (n_moe_layers, B, L,
+    K), "dropped": ()}``, or None for a model without MoE layers."""
+    groups = [r for r in groups if r]
+    if not groups:
+        return None
+    ids = [r["ids"].reshape((-1,) + r["ids"].shape[2:]) for r in groups]
+    return {"ids": jnp.concatenate(ids),
+            "dropped": sum(jnp.sum(r["dropped"]) for r in groups)}
+
+
 def _group_prefill(gp, g: GroupCfg, x, cache, *, positions, impl,
                    compute_dtype, unroll=False):
-    def body(carry, xs):
-        x, aux = carry
+    def body(x, xs):
         params_xs, cache_xs = xs
-        new_cache = {}
+        new_cache, routes = {}, []
         for i, bcfg in enumerate(g.cycle):
             bp = gp["shared"][str(i)] if bcfg.shared else params_xs[str(i)]
             bc = cache_xs.get(str(i), {})
-            x, nc, a = block_prefill(bp, bcfg, x, bc, positions=positions,
+            x, nc, r = block_prefill(bp, bcfg, x, bc, positions=positions,
                                      impl=impl, compute_dtype=compute_dtype)
             if nc:
                 new_cache[str(i)] = nc
-            aux = aux + a
-        return (x, aux), new_cache
+            if r:
+                routes.append(r)
+        return x, (new_cache, _stack_routes(routes))
 
     if unroll:
-        carry = (x, jnp.float32(0.0))
         ys = []
         for r in range(g.repeats):
-            carry, nc = body(carry, (_index_tree(gp["stacked"], r),
-                                     _index_tree(cache, r)))
-            ys.append(nc)
-        (x, aux) = carry
-        return x, _stack_trees(ys), aux
-    xs = (gp["stacked"], cache)
-    (x, aux), new_cache = jax.lax.scan(body, (x, jnp.float32(0.0)), xs,
-                                       length=g.repeats)
-    return x, new_cache, aux
+            x, y = body(x, (_index_tree(gp["stacked"], r),
+                            _index_tree(cache, r)))
+            ys.append(y)
+        new_cache, routes = _stack_trees(ys)
+        return x, new_cache, routes
+    x, (new_cache, routes) = jax.lax.scan(body, x, (gp["stacked"], cache),
+                                          length=g.repeats)
+    return x, new_cache, routes
 
 
 def lm_prefill(p, cfg: LMCfg, tokens, cache, *, prefix_embeds=None,
-               impl: str = "xla", compute_dtype=jnp.bfloat16):
-    """Prefill positions [0, L); returns (last-token logits, filled cache)."""
+               length=None, impl: str = "xla", compute_dtype=jnp.bfloat16,
+               return_route: bool = False):
+    """Prefill positions [0, L); returns (logits at position ``length - 1``
+    (B, 1, V), filled cache[, route]).
+
+    ``length`` (default L) is the true prompt length when ``tokens`` is
+    padded on the right to a compile shape: attention is causal, so no
+    position below ``length`` sees a pad, and the pads' cache entries lie
+    past the position decoding starts from, where a decode step writes
+    before it reads.  ``return_route`` adds the MoE routes ``{"ids":
+    (n_moe_layers, B, L, K), "dropped": ()}`` (None without MoE layers)."""
     x = _embed_inputs(p, cfg, tokens, prefix_embeds,
                       compute_dtype=compute_dtype)
     L = x.shape[1]
     positions = jnp.arange(L)
     x = constrain(x, batch_spec(None, None))
-    new_cache = []
+    new_cache, routes = [], []
     for gp, g, gc in zip(p["groups"], cfg.groups, cache):
-        x, nc, _ = _group_prefill(gp, g, x, gc, positions=positions,
+        x, nc, r = _group_prefill(gp, g, x, gc, positions=positions,
                                   impl=impl, compute_dtype=compute_dtype,
                                   unroll=cfg.unroll)
         new_cache.append(nc)
-    x = _final_norm(p, cfg, x[:, -1:])
-    return _logits(p, cfg, x, compute_dtype=compute_dtype), new_cache
+        routes.append(r)
+    last = L if length is None else length
+    x = _final_norm(p, cfg, jax.lax.dynamic_slice_in_dim(x, last - 1, 1, axis=1))
+    logits = _logits(p, cfg, x, compute_dtype=compute_dtype)
+    if return_route:
+        return logits, new_cache, _merge_routes(routes)
+    return logits, new_cache
+
+
+def _write_index(bcfg: BlockCfg, pos, S: int):
+    """Where a decode step at ``pos`` writes in a sequence mixer's cache of
+    length S: ``pos``, wrapped in a ring-buffer window."""
+    a = bcfg.attn
+    if bcfg.mixer == "attn" and a.ring and a.window is not None:
+        return pos % S
+    return pos
+
+
+def _put_rows(c, e, r, at):
+    """``c`` (repeats, B, S, ...) with the rows ``e`` (B, 1, ...) written in
+    layer r at position ``at``: a scalar for the batch, or one per row."""
+    e = e.astype(c.dtype)
+    if jnp.ndim(at) == 0:
+        return jax.lax.dynamic_update_slice(c, e[None], (r, 0, at) + (0,) * (c.ndim - 3))
+    return c.at[r, jnp.arange(c.shape[1]), at].set(e[:, 0])
 
 
 def _group_decode(gp, g: GroupCfg, x, cache, pos, *, compute_dtype,
                   unroll=False):
-    def body(x, xs):
-        params_xs, cache_xs = xs
-        new_cache = {}
+    """The group's cache rides in the scan's carry.  Each layer of a
+    sequence mixer only reads it and writes its new token's row into it;
+    a recurrent mixer's state is replaced.  No copy of the cache is made."""
+    def body(carry, xs):
+        x, cache = carry
+        params_xs, r = xs
+        routes = []
         for i, bcfg in enumerate(g.cycle):
-            bp = gp["shared"][str(i)] if bcfg.shared else params_xs[str(i)]
-            bc = cache_xs.get(str(i), {})
-            x, nc = block_decode(bp, bcfg, x, bc, pos,
-                                 compute_dtype=compute_dtype)
-            if nc:
-                new_cache[str(i)] = nc
-        return x, new_cache
+            k = str(i)
+            bp = gp["shared"][k] if bcfg.shared else params_xs[k]
+            bc = jax.tree.map(lambda c: c[r], cache.get(k, {}))
+            x, nc, route = block_decode(bp, bcfg, x, bc, pos,
+                                        compute_dtype=compute_dtype)
+            if nc and bcfg.mixer in ("attn", "mla"):
+                rows = jax.tree.map(
+                    lambda c, e: _put_rows(c, e, r, _write_index(bcfg, pos, c.shape[2])),
+                    cache[k]["mixer"], nc["mixer"])
+                cache = {**cache, k: {**cache[k], "mixer": rows}}
+            elif nc:
+                cache = {**cache, k: jax.tree.map(
+                    lambda c, n: jax.lax.dynamic_update_index_in_dim(
+                        c, n.astype(c.dtype), r, axis=0), cache[k], nc)}
+            if route:
+                routes.append(route)
+        return (x, cache), _stack_routes(routes)
 
+    xs = (gp["stacked"], jnp.arange(g.repeats))
     if unroll:
         ys = []
         for r in range(g.repeats):
-            x, nc = body(x, (_index_tree(gp["stacked"], r),
-                             _index_tree(cache, r)))
-            ys.append(nc)
-        return x, _stack_trees(ys)
-    x, new_cache = jax.lax.scan(body, x, (gp["stacked"], cache),
-                                length=g.repeats)
-    return x, new_cache
+            (x, cache), y = body((x, cache), _index_tree(xs, r))
+            ys.append(y)
+        return x, cache, _stack_trees(ys)
+    (x, cache), routes = jax.lax.scan(body, (x, cache), xs, length=g.repeats)
+    return x, cache, routes
 
 
 def lm_decode(p, cfg: LMCfg, token, cache, pos, *,
-              compute_dtype=jnp.bfloat16):
-    """One-token decode.  token: (B, 1) int32; pos: scalar int32 (absolute
-    position of `token`).  Returns (logits (B,1,V), new_cache)."""
+              compute_dtype=jnp.bfloat16, return_route: bool = False):
+    """One-token decode.  token: (B, 1) int32; pos: int32 absolute position
+    of `token`, a scalar for the batch or one per row (B,).  Returns
+    (logits (B,1,V), new_cache[, route]), the route as ``lm_prefill``'s."""
     x = core.embed(p["embed"], token, compute_dtype=compute_dtype)
     if cfg.pos_embed == "learned":
-        x = x + jax.lax.dynamic_slice_in_dim(
-            p["pos"], pos, 1, axis=0).astype(compute_dtype)
+        x = x + jnp.take(p["pos"], jnp.reshape(pos, (-1, 1)),
+                         axis=0).astype(compute_dtype)
     x = constrain(x, batch_spec(None, None))
-    new_cache = []
+    new_cache, routes = [], []
     for gp, g, gc in zip(p["groups"], cfg.groups, cache):
-        x, nc = _group_decode(gp, g, x, gc, pos, compute_dtype=compute_dtype,
-                              unroll=cfg.unroll)
+        x, nc, r = _group_decode(gp, g, x, gc, pos, compute_dtype=compute_dtype,
+                                 unroll=cfg.unroll)
         new_cache.append(nc)
+        routes.append(r)
     x = _final_norm(p, cfg, x)
-    return _logits(p, cfg, x, compute_dtype=compute_dtype), new_cache
+    logits = _logits(p, cfg, x, compute_dtype=compute_dtype)
+    if return_route:
+        return logits, new_cache, _merge_routes(routes)
+    return logits, new_cache

@@ -222,7 +222,7 @@ def whisper_decode(p, cfg: WhisperCfg, token, cache, pos, *,
     x = _decode_embed(p, cfg, token, pos, compute_dtype)
     x = constrain(x, batch_spec(None, None))
     g = cfg.dec_group()
-    x, new_cache = _group_decode(p["dec"], g, x, cache, pos,
+    x, new_cache, _ = _group_decode(p["dec"], g, x, cache, pos,
                                  compute_dtype=compute_dtype,
                                  unroll=cfg.unroll)
     x = core.layernorm(p["dec_norm"], x)

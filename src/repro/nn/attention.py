@@ -336,12 +336,20 @@ def kv_cache_spec(cfg: AttnCfg):
     return {"k": batch_spec(None, "model", None), "v": batch_spec(None, "model", None)}
 
 
+def decode_positions(pos):
+    """A decode step's positions as (B or 1, 1): ``pos`` is a scalar shared
+    by the batch or one position per row (B,)."""
+    return jnp.reshape(pos, (-1, 1))
+
+
 def attn_decode(p, cfg: AttnCfg, x, cache, pos, *,
                 compute_dtype=jnp.bfloat16):
     """One-token decode.  x: (B, 1, D); cache: {"k","v"}: (B, S, Hkv, Dh);
-    pos: scalar int32 — the absolute position of the new token.  Returns
-    (y, new_cache).  For cross-attention the cache holds the (static)
-    encoder k/v and is not updated (pos ignored for masking length)."""
+    pos: the absolute position of the new token, a scalar or one per row
+    (B,).  Returns (y, entry): the cache is only read, and ``entry``
+    {"k","v"} (B, 1, Hkv, Dh) is the new token's row, for the caller to
+    write at ``pos`` (``pos % S`` for a ring buffer).  For cross-attention
+    the cache holds the (static) encoder k/v and is returned unchanged."""
     B = x.shape[0]
     q = _split_heads(linear(p["q"], x, compute_dtype=compute_dtype),
                      cfg.n_heads, cfg.d_head)
@@ -363,36 +371,32 @@ def attn_decode(p, cfg: AttnCfg, x, cache, pos, *,
                          cfg.n_kv_heads, cfg.d_head)
     if cfg.qk_norm:
         k_new = rmsnorm(p["k_norm"], k_new)
+    posb = decode_positions(pos)
     if cfg.rope:
-        cos, sin = rope_cos_sin(pos[None] if jnp.ndim(pos) == 0 else pos,
-                                cfg.d_head, cfg.rope_theta)
+        cos, sin = rope_cos_sin(posb, cfg.d_head, cfg.rope_theta)
         q = apply_rope(q, cos, sin)
         k_new = apply_rope(k_new, cos, sin)
-
-    S = cache["k"].shape[1]
-    ring = cfg.ring and cfg.window is not None
-    write_at = (pos % S) if ring else pos
-    k = jax.lax.dynamic_update_slice_in_dim(
-        cache["k"], k_new.astype(cache["k"].dtype), write_at, axis=1)
-    v = jax.lax.dynamic_update_slice_in_dim(
-        cache["v"], v_new.astype(cache["v"].dtype), write_at, axis=1)
-    k = constrain(k, batch_spec(None, "model", None))
-    v = constrain(v, batch_spec(None, "model", None))
+    k, v = cache["k"], cache["v"]
+    k_new, v_new = k_new.astype(k.dtype), v_new.astype(v.dtype)
 
     scores = _gqa_scores(q, k, scale)  # (B,Hkv,G,1,S)
-    kpos = jnp.arange(S)
-    if ring:
+    S = k.shape[1]
+    kpos = jnp.arange(S)[None, :]
+    if cfg.ring and cfg.window is not None:
         # slot s holds global position pos - ((pos - s) mod S); every live
-        # slot is within the window by construction — only mask slots not
-        # yet written (global position < 0 during warm-up).
-        gpos = pos - jnp.mod(pos - kpos, S)
-        valid = gpos >= 0
+        # slot is within the window by construction — mask slots not yet
+        # written (global position < 0 during warm-up) and the slot this
+        # token is about to take.
+        gpos = posb - jnp.mod(posb - kpos, S)
+        valid = (gpos >= 0) & (gpos < posb)
     else:
-        valid = kpos <= pos
+        valid = kpos < posb
         if cfg.window is not None:
-            valid &= kpos > pos - cfg.window
-    scores = jnp.where(valid[None, None, None, None, :], scores, NEG_INF)
+            valid &= kpos > posb - cfg.window
+    scores = jnp.where(valid[:, None, None, None, :], scores, NEG_INF)
+    scores = jnp.concatenate([scores, _gqa_scores(q, k_new, scale)], axis=-1)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = _gqa_out(probs, v).astype(compute_dtype)
+    out = (_gqa_out(probs[..., :S], v)
+           + _gqa_out(probs[..., S:], v_new)).astype(compute_dtype)
     y = linear(p["o"], _merge_heads(out), compute_dtype=compute_dtype)
-    return y, {"k": k, "v": v}
+    return y, {"k": k_new, "v": v_new}

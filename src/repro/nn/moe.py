@@ -1,16 +1,24 @@
 """Mixture-of-Experts with shared experts + top-k routed experts
-(DeepSeek-V2/V3 style), sort-based capacity dispatch.
+(DeepSeek-V2/V3 style).
 
-Why sort-based: the classic one-hot dispatch tensor (T, E, C) is infeasible at
+Training (``moe_apply``) uses sort-based capacity dispatch.  Why
+sort-based: the classic one-hot dispatch tensor (T, E, C) is infeasible at
 E=256 / T~1M.  We instead sort the (token, expert) assignments by expert id,
 rank tokens within an expert, drop overflow beyond the capacity, and scatter
 into a dense (E, C, d) buffer that is expert-parallel over the "model" mesh
 axis — GSPMD lowers the scatter/gather to all-to-all style collectives.
+
+Serving (``moe_prefill``, ``moe_decode``) drops nothing, and computes the
+share of the layer that this chip's experts give: ``MoECfg.held`` names the
+experts whose weights are here (expert parallelism without its exchange).
+The router keeps its full width; picks of absent experts add nothing, and
+the shared experts run for every token.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,9 +33,13 @@ from .sharding import constrain
 class MoECfg:
     d_model: int
     d_ff: int                      # per routed expert
-    n_experts: int                 # routed experts
+    n_experts: int                 # routed experts (the router's width)
     top_k: int
     n_shared: int = 0              # shared experts (each of size d_ff)
+    norm_topk_prob: bool = True    # renormalise the top-k weights to sum 1
+    # (first, count): the routed experts whose weights this chip holds;
+    # None holds all of them
+    held: Optional[Tuple[int, int]] = None
     capacity_factor: float = 1.25
     aux_coef: float = 0.001
     router_dtype: object = jnp.float32
@@ -37,15 +49,21 @@ class MoECfg:
     # expert-parallel path, §Perf iteration A2)
 
 
+def held_range(cfg: MoECfg) -> Tuple[int, int]:
+    """(first, count) of the routed experts held here."""
+    return cfg.held if cfg.held is not None else (0, cfg.n_experts)
+
+
 def moe_init(key, cfg: MoECfg, *, dtype=jnp.float32):
     kr, ku, kg, kd, ks = jax.random.split(key, 5)
     E, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff
+    n = held_range(cfg)[1]
     scale = 1.0 / math.sqrt(d)
     p = {
         "router": {"w": (jax.random.normal(kr, (d, E)) * scale).astype(jnp.float32)},
-        "up": (jax.random.normal(ku, (E, d, f)) * scale).astype(dtype),
-        "gate": (jax.random.normal(kg, (E, d, f)) * scale).astype(dtype),
-        "down": (jax.random.normal(kd, (E, f, d)) * (1.0 / math.sqrt(f))).astype(dtype),
+        "up": (jax.random.normal(ku, (n, d, f)) * scale).astype(dtype),
+        "gate": (jax.random.normal(kg, (n, d, f)) * scale).astype(dtype),
+        "down": (jax.random.normal(kd, (n, f, d)) * (1.0 / math.sqrt(f))).astype(dtype),
     }
     if cfg.n_shared:
         p["shared"] = mlp_init(
@@ -63,6 +81,22 @@ def moe_spec(cfg: MoECfg):
     if cfg.n_shared:
         s["shared"] = mlp_spec(MLPCfg(cfg.d_model, cfg.d_ff * cfg.n_shared))
     return s
+
+
+def route(router_w, cfg: MoECfg, xt):
+    """Softmax router over all ``n_experts`` in float32 (on a TPU too, where
+    a float32 product otherwise rounds its operands to bfloat16), greedy
+    top-k: returns the
+    pick weights (T, K) (renormalised where ``norm_topk_prob``), the picked
+    expert ids (T, K) and the probabilities (T, E)."""
+    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                        router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    w, ids = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-9)
+    return w, ids, probs
 
 
 def _capacity(T: int, cfg: MoECfg) -> int:
@@ -84,11 +118,7 @@ def _local_dispatch_combine(router_w, up, gate, down, xl, cfg: MoECfg,
     cap = max(int(math.ceil(T * K * cfg.capacity_factor / E)), K)
     xt = xl.reshape(T, D)
 
-    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
-                        router_w.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    w, ids = jax.lax.top_k(probs, K)
-    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-9)
+    w, ids, probs = route(router_w, cfg, xt)
 
     flat_ids = ids.reshape(T * K)
     flat_w = w.reshape(T * K)
@@ -163,7 +193,11 @@ def moe_apply_shardmap(p, cfg: MoECfg, x, *, compute_dtype=jnp.bfloat16):
 
 
 def moe_apply(p, cfg: MoECfg, x, *, compute_dtype=jnp.bfloat16):
-    """x: (B, L, D) -> (y, aux_loss)."""
+    """x: (B, L, D) -> (y, aux_loss).  Training dispatch, over every
+    routed expert."""
+    if cfg.held is not None:
+        raise ValueError("capacity dispatch runs every routed expert; "
+                         "a held share is served by moe_prefill/moe_decode")
     if cfg.dispatch == "shardmap":
         from .sharding import current_mesh
         if current_mesh() is not None:
@@ -176,11 +210,7 @@ def moe_apply(p, cfg: MoECfg, x, *, compute_dtype=jnp.bfloat16):
     cap = _capacity(T, cfg)
     xt = x.reshape(T, D)
 
-    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
-                        p["router"]["w"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    w, ids = jax.lax.top_k(probs, K)                     # (T,K)
-    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-9)  # renormalize top-k
+    w, ids, probs = route(p["router"]["w"], cfg, xt)     # (T,K)
 
     # --- flatten assignments and sort by expert id --------------------------
     flat_ids = ids.reshape(T * K)
@@ -228,3 +258,90 @@ def moe_apply(p, cfg: MoECfg, x, *, compute_dtype=jnp.bfloat16):
     mean_prob = jnp.mean(probs, axis=0)
     aux = cfg.aux_coef * E * jnp.sum(frac * mean_prob)
     return y, aux
+
+
+# ---------------------------------------------------------------------------
+# serving: dropless, over the held experts
+# ---------------------------------------------------------------------------
+
+def _held_picks(cfg: MoECfg, ids, w):
+    """Each pick's local expert index, ``count`` for an absent expert, and
+    its weight, 0 for an absent expert."""
+    first, count = held_range(cfg)
+    local = ids - first
+    mine = (local >= 0) & (local < count)
+    return jnp.where(mine, local, count), jnp.where(mine, w, 0.0)
+
+
+def _shared(p, cfg: MoECfg, x, compute_dtype):
+    return mlp_apply(p["shared"], MLPCfg(cfg.d_model, cfg.d_ff * cfg.n_shared),
+                     x, compute_dtype=compute_dtype)
+
+
+def moe_prefill(p, cfg: MoECfg, x, *, compute_dtype=jnp.bfloat16):
+    """x: (B, L, D) -> (y, {"ids": (B, L, K) picked experts, "dropped": held
+    picks left uncomputed}).  The (token, pick) pairs are sorted by held
+    expert and each held expert runs on exactly its rows as one ragged
+    matmul (``jax.lax.ragged_dot``); no capacity, so nothing is dropped.
+    Picks of absent experts sort last, past every group, and add nothing."""
+    B, L, D = x.shape
+    T, K = B * L, cfg.top_k
+    count = held_range(cfg)[1]
+    xt = x.reshape(T, D)
+    w, ids, _ = route(p["router"]["w"], cfg, xt)
+    local, w = _held_picks(cfg, ids, w)
+    flat = local.reshape(T * K)
+    order = jnp.argsort(flat, stable=True)
+    rows = order // K
+    sizes = jnp.bincount(flat, length=count + 1)[:count].astype(jnp.int32)
+    xs = xt[rows].astype(compute_dtype)
+    cd = lambda a: a.astype(compute_dtype)
+    h = (silu(jax.lax.ragged_dot(xs, cd(p["gate"]), sizes))
+         * jax.lax.ragged_dot(xs, cd(p["up"]), sizes))
+    out = jax.lax.ragged_dot(h, cd(p["down"]), sizes)
+    computed = jnp.arange(T * K) < jnp.sum(sizes)
+    out = jnp.where(computed[:, None], out, 0)
+    contrib = out * w.reshape(T * K)[order][:, None].astype(compute_dtype)
+    y = jnp.zeros((T, D), compute_dtype).at[rows].add(contrib)
+    y = y.reshape(B, L, D)
+    if "shared" in p:
+        y = y + _shared(p, cfg, x, compute_dtype)
+    dropped = jnp.sum(flat < count) - jnp.sum(computed)
+    return y, {"ids": ids.reshape(B, L, K), "dropped": dropped.astype(jnp.int32)}
+
+
+def moe_decode(p, cfg: MoECfg, x, *, compute_dtype=jnp.bfloat16):
+    """x: (B, 1, D), one token per sequence -> (y, {"ids", "dropped"}) as
+    ``moe_prefill``.  A decode step reads every held expert's weights
+    whatever its routing, so each held expert runs on every token as one
+    dense product, and the picks weight the results (unpicked and absent
+    experts weigh 0): dropless, and the layer's slice of the stacked
+    weights feeds the products directly (``ragged_dot`` copies each
+    layer's expert weights out of the stack first, on a v5e)."""
+    B, L, D = x.shape
+    T = B * L
+    count = held_range(cfg)[1]
+    xt = x.reshape(T, D)
+    w, ids, _ = route(p["router"]["w"], cfg, xt)
+    local, w = _held_picks(cfg, ids, w)
+    gates = jnp.zeros((T, count + 1), jnp.float32).at[
+        jnp.arange(T)[:, None], local].add(w)[:, :count]
+    xc = xt.astype(compute_dtype)
+    cd = lambda a: a.astype(compute_dtype)
+    h = (silu(jnp.einsum("td,edf->tef", xc, cd(p["gate"])))
+         * jnp.einsum("td,edf->tef", xc, cd(p["up"])))
+    out = jnp.einsum("tef,efd->ted", h, cd(p["down"]))
+    y = jnp.einsum("ted,te->td", out, gates.astype(compute_dtype)).reshape(B, L, D)
+    if "shared" in p:
+        y = y + _shared(p, cfg, x, compute_dtype)
+    return y, {"ids": ids.reshape(B, L, cfg.top_k),
+               "dropped": jnp.zeros((), jnp.int32)}
+
+
+def held_load(cfg: MoECfg, ids, valid):
+    """Picks per held expert: ``ids`` (..., K) picked experts, ``valid``
+    (...) the tokens to count -> (count,) int32."""
+    local, _ = _held_picks(cfg, ids, jnp.zeros(ids.shape, jnp.float32))
+    count = held_range(cfg)[1]
+    local = jnp.where(valid[..., None], local, count)
+    return jnp.bincount(local.reshape(-1), length=count + 1)[:count].astype(jnp.int32)

@@ -1,9 +1,18 @@
 """Continuous-batching serving engine for CompositeLM models.
 
 Slot-based: a fixed ``max_batch`` of independent sequences share one decode
-step (vmapped single-sequence decode, so every slot keeps its own position).
-Prefill runs per-request at bucketed lengths (pow-2 padding bounds the
-number of compiled variants) and its cache is inserted into the free slot.
+step, each slot at its own position.
+Prefill runs per request at the prompt's true length: the prompt is padded
+on the right to a power-of-two bucket, which only bounds the number of
+compiled shapes; the first token comes from the last prompt position and
+decoding continues from the prompt's length.  Prefill writes straight into
+the free slot of the cache, and both the prefill and the decode step donate
+the cache, so one copy of it is ever live.
+
+Spans ``engine.prefill`` and ``engine.decode_step`` mark each call on the
+profiler's trace (``jax.profiler.TraceAnnotation``, nothing when no trace is
+taken); ``counters()`` gives the tokens served and, for a mixture-of-experts
+model, the picks per held expert and the picks left uncomputed.
 
 This is the substrate the paper assumes exists at the edge: the thing that
 actually executes a cached GenAI model for a user request.
@@ -19,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.lm import (LMCfg, lm_decode, lm_init_cache, lm_prefill)
+from repro.nn.moe import held_load, held_range
 
 
 @dataclasses.dataclass
@@ -43,6 +53,15 @@ class _Slot:
     generated: Optional[list] = None
 
 
+def _moe_cfg(cfg: LMCfg):
+    """The MoE configuration of ``cfg``'s expert layers, or None."""
+    for g in cfg.groups:
+        for b in g.cycle:
+            if b.ffn == "moe":
+                return b.moe
+    return None
+
+
 class Engine:
     def __init__(self, cfg: LMCfg, params, serve_cfg: ServeCfg):
         self.cfg = cfg
@@ -52,34 +71,94 @@ class Engine:
         self.cache = lm_init_cache(cfg, B, S)
         self.pos = np.zeros(B, np.int32)          # next position per slot
         self.slots: List[_Slot] = [_Slot() for _ in range(B)]
-        self.last_tok = np.zeros((B, 1), np.int32)
+        self.last_tok = np.zeros(B, np.int32)
+        self.moe = _moe_cfg(cfg)
+        self.n_moe_layers = sum(g.repeats * sum(b.ffn == "moe" for b in g.cycle)
+                                for g in cfg.groups)
+        # device arrays of the latest call: its logits, and the experts each
+        # of its tokens picked in every MoE layer (None without MoE layers)
+        self.logits = self.route = None
+        self._counts = self._zero_counts()
+        self._stats = self._zero_stats()
 
-        cache_axes = jax.tree.map(lambda _: 1, self.cache)
+        def decode(params, state, cache, stats):
+            tok, pos, active = state[0], state[1], state[2] > 0
+            logits, cache, route = lm_decode(params, cfg, tok[:, None], cache,
+                                             pos, return_route=True)
+            logits = logits[:, 0]
+            ids = None
+            if route is not None:
+                ids = route["ids"][:, :, 0]               # (layers, B, K)
+                load = jax.vmap(lambda i: held_load(self.moe, i, active))(ids)
+                ids = jnp.moveaxis(ids, 0, 1)
+                stats = {**stats,
+                         "decode_load": stats["decode_load"] + load,
+                         "decode_load_max": jnp.maximum(stats["decode_load_max"],
+                                                        load),
+                         "decode_touched": stats["decode_touched"]
+                         + jnp.sum(load > 0),
+                         "dropped": stats["dropped"] + route["dropped"]}
+            return jnp.argmax(logits, axis=-1), logits, ids, cache, stats
 
-        def _decode1(params, tok, cache, pos):
-            # tok: (1,) -> (1,1); vmap strips the batch axis from the cache,
-            # so re-insert a singleton batch dim for the model and squeeze
-            # it back out for the vmapped out_axes.
-            cache = jax.tree.map(lambda c: jnp.expand_dims(c, 1), cache)
-            logits, cache = lm_decode(params, cfg, tok[None], cache, pos)
-            cache = jax.tree.map(lambda c: jnp.squeeze(c, 1), cache)
-            return logits, cache
+        self._decode = jax.jit(decode, donate_argnums=(2, 3))
 
-        self._vdecode = jax.jit(jax.vmap(
-            _decode1, in_axes=(None, 0, cache_axes, 0),
-            out_axes=(0, cache_axes)))
-
-        self._prefill = jax.jit(
-            lambda params, toks, cache: lm_prefill(params, cfg, toks, cache))
-
-        self._sub_cache = jax.jit(
-            lambda cache, i: jax.tree.map(
-                lambda c: jax.lax.dynamic_slice_in_dim(c, i, 1, axis=1),
-                cache))
-        self._set_cache = jax.jit(
-            lambda cache, sub, i: jax.tree.map(
+        def prefill(params, toks, length, cache, slot, stats):
+            sub = jax.tree.map(
+                lambda c: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=1), cache)
+            logits, sub, route = lm_prefill(params, cfg, toks, sub,
+                                            length=length, return_route=True)
+            cache = jax.tree.map(
                 lambda c, s: jax.lax.dynamic_update_slice_in_dim(
-                    c, s.astype(c.dtype), i, axis=1), cache, sub))
+                    c, s.astype(c.dtype), slot, axis=1), cache, sub)
+            logits = logits[0, 0]
+            ids = None
+            if route is not None:
+                ids = route["ids"][:, 0]                  # (layers, Lb, K)
+                valid = jnp.arange(toks.shape[1]) < length
+                load = jax.vmap(lambda i: held_load(self.moe, i, valid))(ids)
+                stats = {**stats,
+                         "prefill_load": stats["prefill_load"] + load,
+                         "dropped": stats["dropped"] + route["dropped"]}
+            return jnp.argmax(logits), logits, ids, cache, stats
+
+        self._prefill = jax.jit(prefill, donate_argnums=(3, 5))
+
+    # -- counters --------------------------------------------------------------
+
+    @staticmethod
+    def _zero_counts() -> dict:
+        return {"prefills": 0, "prefill_tokens": 0, "decode_steps": 0,
+                "decode_tokens": 0, "live_tokens": 0}
+
+    def _zero_stats(self) -> dict:
+        if self.moe is None:
+            return {}
+        shape = (self.n_moe_layers, held_range(self.moe)[1])
+        z = lambda s: jnp.zeros(s, jnp.int32)
+        return {"decode_load": z(shape), "decode_load_max": z(shape),
+                "decode_touched": z(()), "prefill_load": z(shape),
+                "dropped": z(())}
+
+    def counters(self) -> dict:
+        """Counts since the engine started (or its warm-up ended): prefills
+        and their prompt tokens; decode steps, the tokens they decoded
+        (active slots summed over steps) and the cache positions those
+        tokens attended (``live_tokens``).  With MoE layers also, from the
+        device: ``expert_picks`` (picks of held experts by decoded tokens,
+        over every MoE layer), ``expert_picks_max`` (the most one held
+        expert of one layer took in one step), ``experts_touched`` (held
+        experts, over layers and steps, that a step ran for at least one
+        pick), ``prefill_expert_picks`` and ``dropped`` (held picks left
+        uncomputed, prefill and decode)."""
+        out = dict(self._counts)
+        if self._stats:
+            st = jax.device_get(self._stats)
+            out.update(expert_picks=int(st["decode_load"].sum()),
+                       expert_picks_max=int(st["decode_load_max"].max()),
+                       experts_touched=int(st["decode_touched"]),
+                       prefill_expert_picks=int(st["prefill_load"].sum()),
+                       dropped=int(st["dropped"]))
+        return out
 
     # -- admission -------------------------------------------------------------
 
@@ -89,20 +168,27 @@ class Engine:
                 return i
         return None
 
+    def _run_prefill(self, toks, length, slot):
+        nxt, self.logits, self.route, self.cache, self._stats = self._prefill(
+            self.params, toks, np.int32(length), self.cache, np.int32(slot),
+            self._stats)
+        return nxt
+
     def admit(self, uid: int, prompt: np.ndarray, max_new_tokens: int) -> int:
         """Prefill ``prompt`` into a free slot; returns the slot index."""
         slot = self.free_slot()
         assert slot is not None, "no free slot"
         L = int(prompt.shape[-1])
-        Lb = min(_bucket(L), self.sc.max_seq)
-        toks = np.full((1, Lb), self.sc.pad_id, np.int32)
+        assert 0 < L < self.sc.max_seq, (L, self.sc.max_seq)
+        toks = np.full((1, min(_bucket(L), self.sc.max_seq)), self.sc.pad_id,
+                       np.int32)
         toks[0, :L] = prompt
-        sub = self._sub_cache(self.cache, slot)
-        logits, sub = self._prefill(self.params, jnp.asarray(toks), sub)
-        self.cache = self._set_cache(self.cache, sub, slot)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        self.pos[slot] = Lb
-        self.last_tok[slot, 0] = nxt
+        with jax.profiler.TraceAnnotation("engine.prefill"):
+            nxt = int(self._run_prefill(toks, L, slot))
+        self._counts["prefills"] += 1
+        self._counts["prefill_tokens"] += L
+        self.pos[slot] = L
+        self.last_tok[slot] = nxt
         self.slots[slot] = _Slot(uid=uid, budget=max_new_tokens,
                                  generated=[nxt])
         return slot
@@ -112,13 +198,20 @@ class Engine:
     def active(self) -> List[int]:
         return [i for i, s in enumerate(self.slots) if s.uid is not None]
 
+    def _run_decode(self, active):
+        state = np.stack([self.last_tok, self.pos, active.astype(np.int32)])
+        nxt, self.logits, self.route, self.cache, self._stats = self._decode(
+            self.params, state, self.cache, self._stats)
+        return nxt
+
     def step(self):
         """One continuous-batching decode step over all slots."""
-        logits, self.cache = self._vdecode(
-            self.params, jnp.asarray(self.last_tok),
-            self.cache, jnp.asarray(self.pos))
-        nxt = np.asarray(jnp.argmax(logits[:, 0, -1, :], axis=-1),
-                         np.int32)
+        active = np.array([s.uid is not None for s in self.slots])
+        with jax.profiler.TraceAnnotation("engine.decode_step"):
+            nxt = np.asarray(self._run_decode(active), np.int32)
+        self._counts["decode_steps"] += 1
+        self._counts["decode_tokens"] += int(active.sum())
+        self._counts["live_tokens"] += int((self.pos[active] + 1).sum())
         finished = []
         for i, s in enumerate(self.slots):
             if s.uid is None:
@@ -132,8 +225,18 @@ class Engine:
                 finished.append((s.uid, list(s.generated)))
                 self.slots[i] = _Slot()
             else:
-                self.last_tok[i, 0] = tok
+                self.last_tok[i] = tok
         return finished
+
+    def warm_up(self, prompt_lengths):
+        """Compile the prefill for the bucket of each of ``prompt_lengths``
+        and the decode step, on an idle engine; counters start afresh."""
+        assert not self.active(), "warm up an idle engine"
+        for Lb in sorted({min(_bucket(int(L)), self.sc.max_seq)
+                          for L in prompt_lengths}):
+            self._run_prefill(np.full((1, Lb), self.sc.pad_id, np.int32), 1, 0)
+        jax.block_until_ready(self._run_decode(np.zeros(self.sc.max_batch, bool)))
+        self._counts, self._stats = self._zero_counts(), self._zero_stats()
 
     # -- convenience ------------------------------------------------------------
 

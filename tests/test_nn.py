@@ -52,6 +52,13 @@ def test_rope_preserves_norm_and_relative_phase():
     np.testing.assert_allclose(float(d02), float(d13), rtol=1e-5)
 
 
+def _write_row(cache, row, t):
+    """Decode reads the cache; the caller writes the token's row at t."""
+    return jax.tree.map(
+        lambda c, r: jax.lax.dynamic_update_slice_in_dim(c, r, t, axis=1),
+        cache, row)
+
+
 @pytest.mark.parametrize("n_kv,window,qk_norm,bias", [
     (4, None, False, False), (2, None, False, True), (1, 8, True, False)])
 def test_attention_decode_matches_forward(n_kv, window, qk_norm, bias):
@@ -63,8 +70,9 @@ def test_attention_decode_matches_forward(n_kv, window, qk_norm, bias):
     cache = init_kv_cache(2, 16, cfg, jnp.float32)
     y = None
     for t in range(12):
-        y, cache = attn_decode(p, cfg, x[:, t:t + 1], cache, jnp.int32(t),
-                               **F32)
+        y, row = attn_decode(p, cfg, x[:, t:t + 1], cache, jnp.int32(t),
+                             **F32)
+        cache = _write_row(cache, row, t)
     np.testing.assert_allclose(np.asarray(y[:, 0]),
                                np.asarray(full[:, -1]), rtol=2e-4, atol=2e-4)
 
@@ -78,20 +86,22 @@ def test_mla_absorbed_decode_matches_expanded_forward():
     cache = init_mla_cache(2, 12, cfg, jnp.float32)
     y = None
     for t in range(10):
-        y, cache = mla_decode(p, cfg, x[:, t:t + 1], cache, jnp.int32(t),
-                              **F32)
+        y, row = mla_decode(p, cfg, x[:, t:t + 1], cache, jnp.int32(t),
+                            **F32)
+        cache = _write_row(cache, row, t)
     np.testing.assert_allclose(np.asarray(y[:, 0]),
                                np.asarray(full[:, -1]), rtol=2e-4, atol=2e-4)
 
 
 def test_mla_cache_is_compressed():
-    """MLA decode cache bytes/token must be (kv_lora + rope_dim), far below
-    2·H·Dh — the edge-memory win described in DESIGN.md."""
+    """MLA decode cache bytes/token must be (kv_lora + rope_dim), rounded
+    up to whole 128-lane tiles as the TPU stores a row, far below 2·H·Dh —
+    the edge-memory win described in DESIGN.md."""
     cfg = MLACfg(64, 16, kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8,
                  v_head_dim=16)
     cache = init_mla_cache(1, 1, cfg)
     per_tok = sum(x.size for x in jax.tree.leaves(cache))
-    assert per_tok == cfg.kv_lora_rank + cfg.qk_rope_dim
+    assert per_tok == 128 * -(-(cfg.kv_lora_rank + cfg.qk_rope_dim) // 128)
     assert per_tok < 2 * cfg.n_heads * cfg.v_head_dim
 
 

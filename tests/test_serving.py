@@ -30,12 +30,14 @@ def test_bucket_is_pow2_with_floor_8():
 
 
 def test_admit_pads_prompt_to_bucket(lm):
+    """The bucket is only the prefill's compile shape: decoding continues
+    from the prompt's true length."""
     cfg, params = lm
     eng = Engine(cfg, params, ServeCfg(max_batch=2, max_seq=64))
     slot = eng.admit(7, np.arange(3, dtype=np.int32), 4)
-    assert eng.pos[slot] == 8            # 3 -> bucket 8
+    assert eng.pos[slot] == 3            # prefilled in bucket 8
     slot2 = eng.admit(8, np.arange(9, dtype=np.int32) % cfg.vocab, 4)
-    assert eng.pos[slot2] == 16          # 9 -> bucket 16
+    assert eng.pos[slot2] == 9           # prefilled in bucket 16
     assert eng.slots[slot].uid == 7 and eng.slots[slot2].uid == 8
 
 
@@ -94,7 +96,7 @@ def test_context_cap_finishes_slot(lm):
     cfg, params = lm
     eng = Engine(cfg, params, ServeCfg(max_batch=1, max_seq=16))
     done, _ = eng.run([(0, np.arange(8, dtype=np.int32), 100)])
-    # pos starts at bucket 8; decode steps run pos through 9..15
+    # pos starts at the prompt's length 8; decode steps run pos through 9..15
     assert len(done[0]) == 8
     assert eng.free_slot() == 0
 
