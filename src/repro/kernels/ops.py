@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from .ddpm_step import ddpm_step_2d
 from .flash_attention import flash_attention_bhld
+from .mla_decode import mla_decode_bhw
 from .ssd_scan import ssd_scan_bhlp
 
 
@@ -43,6 +44,24 @@ def flash_attention(q, k, v, *, causal: bool = True,
         vp.transpose(0, 2, 1, 3), causal=causal, window=window,
         bq=bq_, bk=bk_, interpret=_interpret())
     return o.transpose(0, 2, 1, 3)[:, :L]
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "width", "window"))
+def mla_decode(q, own, kv, pos, layer, *, scale: float, width: int,
+               window: Optional[int] = None):
+    """Absorbed-MLA decode attention.  q: (B, H, W) query rows; own: (B, W)
+    the new tokens' cache rows; kv: the stacked cache (R, B, S, W), read at
+    ``layer``; pos: the new tokens' positions, a scalar or (B,).  Returns
+    the context's first ``width`` columns (B, H, width) in q.dtype.  With
+    ``own=None``, the split form of ``mla_decode_bhw``: (o, lse) over this
+    device's rows of a cache split along its sequence."""
+    B = q.shape[0]
+    pos = jnp.broadcast_to(jnp.reshape(pos, (-1,)), (B,)).astype(jnp.int32)
+    layer = jnp.reshape(layer, (1,)).astype(jnp.int32)
+    if own is not None:
+        own = own.astype(kv.dtype)[:, None]
+    return mla_decode_bhw(q, own, kv, pos, layer, scale=scale, width=width,
+                          window=window, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))

@@ -32,6 +32,32 @@ def flash_attention_ref(q, k, v, *, causal: bool = True,
     return o.reshape(B, L, H, D).astype(q.dtype)
 
 
+def mla_decode_ref(q, own, kv, pos, layer, *, scale: float, width: int,
+                   window: Optional[int] = None):
+    """Absorbed-MLA decode attention in XLA: scores of q (B, H, W) against
+    every row of the stacked cache kv (R, B, S, W) at ``layer`` and the own
+    rows (B, W); rows at or past ``pos`` (and, with a window, at or before
+    ``pos - window``) masked; softmax; the context's first ``width``
+    columns (B, H, width) in q.dtype."""
+    kv = kv[layer]
+    S = kv.shape[1]
+    posb = jnp.reshape(pos, (-1, 1))
+    scores = jnp.einsum("bhc,bsc->bhs", q, kv,
+                        preferred_element_type=jnp.float32) * scale
+    own_s = jnp.einsum("bhc,bc->bh", q, own,
+                       preferred_element_type=jnp.float32) * scale
+    kpos = jnp.arange(S)[None, :]
+    valid = kpos < posb
+    if window is not None:
+        valid &= kpos > posb - window
+    scores = jnp.where(valid[:, None, :], scores, -1e30)
+    probs = jax.nn.softmax(jnp.concatenate([scores, own_s[..., None]], -1), -1)
+    ctx = (jnp.einsum("bhs,bsc->bhc", probs[..., :S].astype(kv.dtype),
+                      kv).astype(jnp.float32)
+           + probs[..., S, None] * own.astype(jnp.float32)[:, None])
+    return ctx[..., :width].astype(q.dtype)
+
+
 def ssd_scan_ref(x, dt, A, Bm, Cm, D, *, chunk: int = 128):
     """Chunked-SSD oracle — delegates to the nn-layer reference (itself
     validated against a step-by-step recurrence in tests)."""

@@ -248,13 +248,14 @@ def block_prefill(p, cfg: BlockCfg, x, cache, *, positions=None, enc=None,
     return x, new, route
 
 
-def block_decode(p, cfg: BlockCfg, x, cache, pos, *,
+def block_decode(p, cfg: BlockCfg, x, cache, pos, *, layer=0,
                  compute_dtype=jnp.bfloat16):
-    """One-token step.  x: (B,1,D); pos: int32, a scalar or one per row.
-    Returns (x, new, route): ``new["mixer"]`` is the new token's cache row
-    for an attention mixer (the cache is only read; the caller writes the
-    row), the whole new state for a recurrent one; ``route`` as
-    ``block_prefill``'s."""
+    """One-token step.  x: (B,1,D); pos: int32, a scalar or one per row;
+    an MLA mixer's cache is the group's, stacked over layers, read at
+    ``layer``.  Returns (x, new, route): ``new["mixer"]`` is the new
+    token's cache row for an attention mixer (the cache is only read; the
+    caller writes the row), the whole new state for a recurrent one;
+    ``route`` as ``block_prefill``'s."""
     route = {}
     new = dict(cache)
     if cfg.mixer == "attn":
@@ -266,7 +267,7 @@ def block_decode(p, cfg: BlockCfg, x, cache, pos, *,
     elif cfg.mixer == "mla":
         y, new["mixer"] = mla_decode(p["mixer"], cfg.mla,
                                      _norm_apply(cfg.norm, p["norm1"], x),
-                                     cache["mixer"], pos,
+                                     cache["mixer"], pos, layer=layer,
                                      compute_dtype=compute_dtype)
         x = x + y
     elif cfg.mixer == "ssm":

@@ -427,7 +427,8 @@ def _group_decode(gp, g: GroupCfg, x, cache, pos, *, compute_dtype,
                   unroll=False):
     """The group's cache rides in the scan's carry.  Each layer of a
     sequence mixer only reads it and writes its new token's row into it;
-    a recurrent mixer's state is replaced.  No copy of the cache is made."""
+    a recurrent mixer's state is replaced.  No copy of the cache is made:
+    an MLA mixer's decode kernel reads its layer in the stacked cache."""
     def body(carry, xs):
         x, cache = carry
         params_xs, r = xs
@@ -435,8 +436,10 @@ def _group_decode(gp, g: GroupCfg, x, cache, pos, *, compute_dtype,
         for i, bcfg in enumerate(g.cycle):
             k = str(i)
             bp = gp["shared"][k] if bcfg.shared else params_xs[k]
-            bc = jax.tree.map(lambda c: c[r], cache.get(k, {}))
-            x, nc, route = block_decode(bp, bcfg, x, bc, pos,
+            bc = {n: c if n == "mixer" and bcfg.mixer == "mla"
+                  else jax.tree.map(lambda a: a[r], c)
+                  for n, c in cache.get(k, {}).items()}
+            x, nc, route = block_decode(bp, bcfg, x, bc, pos, layer=r,
                                         compute_dtype=compute_dtype)
             if nc and bcfg.mixer in ("attn", "mla"):
                 rows = jax.tree.map(

@@ -4,7 +4,12 @@ Prefill uses the expanded (standard) formulation; decode uses the *absorbed*
 formulation (W_UK folded into the query, W_UV folded into the output) so the
 per-token KV-cache is just ``c_kv`` (kv_lora_rank) + ``k_rope`` — the paper's
 edge-memory constraint is directly served by this: cache bytes drop from
-2·H·Dh to (kv_lora + d_rope) per token (~9x for V3).
+2·H·Dh to (kv_lora + d_rope) per token (~9x for V3).  Decode attention runs
+in the Pallas kernel ``kernels/mla_decode.py``, which reads each slot's live
+cache rows once, in place in the stacked cache; the XLA absorbed form is
+only its test oracle (``kernels/ref.py``).  Under a mesh the kernel runs on
+each device's shard of the cache, whose sequence ``mla_cache_spec`` splits
+over "model", and the shards' parts are combined by their log-sum-exps.
 """
 from __future__ import annotations
 
@@ -16,10 +21,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels import ops as kops
+
 from .core import linear, linear_init, rmsnorm, rmsnorm_init
 from .rotary import YaRN, apply_rope, rope_cos_sin
 from .attention import NEG_INF, causal_window_mask, decode_positions
-from .sharding import batch_spec, constrain
+from .sharding import batch_spec, constrain, current_mesh, fit_spec
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,11 +172,54 @@ def mla_cache_spec(cfg: MLACfg):
     return {"kv": batch_spec("model", None)}
 
 
-def mla_decode(p, cfg: MLACfg, x, cache, pos, *, compute_dtype=jnp.bfloat16):
-    """One-token absorbed-MLA decode.  x: (B,1,D); cache kv: (B,S,C+dr);
-    pos: the new token's position, a scalar or one per row (B,).  Returns
-    (y, entry): the cache is only read, and ``entry`` {"kv": (B,1,C+dr)} is
-    the new token's row, for the caller to write at ``pos``."""
+def _attend(cfg: MLACfg, q, own, kv, pos, layer):
+    """Decode attention of the absorbed query rows q (B, H, W) over the
+    stacked cache kv (R, B, S, W) at ``layer`` and the own rows (B, W).
+    Under a mesh, one kernel per device on its shard of the cache, laid out
+    by ``mla_cache_spec``; where that splits the sequence, each device's
+    part (context and log-sum-exp over its rows) and the own row are
+    combined across the split: a Pallas call has no partitioning rule, so
+    left to GSPMD it would gather the whole cache onto every device."""
+    kw = dict(scale=cfg.softmax_scale, width=cfg.kv_lora_rank,
+              window=cfg.window)
+    mesh = current_mesh()
+    if mesh is None:
+        return kops.mla_decode(q, own, kv, pos, layer, **kw)
+    B = q.shape[0]
+    spec = fit_spec(P(None, *mla_cache_spec(cfg)["kv"]), kv.shape, mesh)
+    b_ax, s_ax = spec[1], spec[2]
+
+    def local(q, own, kv, pos, layer):
+        if s_ax is None:
+            return kops.mla_decode(q, own, kv, pos, layer, **kw)
+        first = jax.lax.axis_index(s_ax) * kv.shape[2]
+        o, lse = kops.mla_decode(q, None, kv, pos - first, layer, **kw)
+        own_lse = jnp.einsum("bhc,bc->bh", q, own,
+                             preferred_element_type=jnp.float32
+                             )[..., None] * kw["scale"]
+        top = jnp.maximum(jax.lax.pmax(lse, s_ax), own_lse)
+        w, w_own = jnp.exp(lse - top), jnp.exp(own_lse - top)
+        num = (jax.lax.psum(w * o, s_ax)
+               + w_own * own[:, None, :kw["width"]].astype(jnp.float32))
+        return (num / (jax.lax.psum(w, s_ax) + w_own)).astype(q.dtype)
+
+    pos = jnp.broadcast_to(jnp.reshape(pos, (-1,)), (B,)).astype(jnp.int32)
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(P(b_ax, None, None), P(b_ax, None), spec, P(b_ax), P()),
+        out_specs=P(b_ax, None, None), check_vma=False,
+    )(q, own, kv, pos, jnp.asarray(layer, jnp.int32))
+
+
+def mla_decode(p, cfg: MLACfg, x, cache, pos, *, layer,
+               compute_dtype=jnp.bfloat16):
+    """One-token absorbed-MLA decode.  x: (B,1,D); cache kv: stacked over
+    layers (R,B,S,C+dr), read at ``layer``; pos: the new token's position,
+    a scalar or one per row (B,).  Returns (y, entry): the cache is only
+    read, and ``entry`` {"kv": (B,1,C+dr)} is the new token's row, for the
+    caller to write at ``pos``.  Attention runs in the decode kernel
+    (``kernels/mla_decode.py``), which reads each slot's rows before ``pos``
+    once, in place."""
     B = x.shape[0]
     H = cfg.n_heads
     C = cfg.kv_lora_rank
@@ -189,25 +239,8 @@ def mla_decode(p, cfg: MLACfg, x, cache, pos, *, compute_dtype=jnp.bfloat16):
     # [c_kv; k_rope] whole: q_lat . c_kv + q_rope . k_rope
     q_lat = jnp.einsum("blhd,chd->blhc", q_nope, W_uk)
     q = mla_cache_entry(cfg, q_lat, q_rope)
-    scale = cfg.softmax_scale
-    # the cached positions before this token, and the token itself
-    scores = jnp.einsum("blhc,bsc->bhls", q, kv,
-                        preferred_element_type=jnp.float32) * scale
-    own = jnp.einsum("blhc,blc->bhl", q, entry,
-                     preferred_element_type=jnp.float32) * scale
-    S = kv.shape[1]
-    kpos = jnp.arange(S)[None, :]
-    valid = kpos < posb
-    if cfg.window is not None:
-        valid &= kpos > posb - cfg.window
-    scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
-    probs = jax.nn.softmax(jnp.concatenate([scores, own[..., None]], axis=-1),
-                           axis=-1)
-    ctx = (jnp.einsum("bhls,bsc->blhc", probs[..., :S].astype(kv.dtype),
-                      kv).astype(jnp.float32)
-           + jnp.einsum("bhl,blc->blhc", probs[..., S],
-                        entry.astype(jnp.float32)))[..., :C]
-    out = jnp.einsum("blhc,chv->blhv", ctx.astype(compute_dtype), W_uv)
+    ctx = _attend(cfg, q[:, 0], entry[:, 0], kv, pos, layer)     # (B,H,C)
+    out = jnp.einsum("bhc,chv->bhv", ctx.astype(compute_dtype), W_uv)
     y = linear(p["o"], out.reshape(B, 1, H * cfg.v_head_dim),
                compute_dtype=compute_dtype)
     return y, {"kv": entry}

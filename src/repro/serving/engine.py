@@ -11,8 +11,9 @@ the cache, so one copy of it is ever live.
 
 Spans ``engine.prefill`` and ``engine.decode_step`` mark each call on the
 profiler's trace (``jax.profiler.TraceAnnotation``, nothing when no trace is
-taken); ``counters()`` gives the tokens served and, for a mixture-of-experts
-model, the picks per held expert and the picks left uncomputed.
+taken); ``counters()`` gives the tokens served, the cache rows the MLA
+decode kernel read and, for a mixture-of-experts model, the picks per held
+expert and the picks left uncomputed.
 
 This is the substrate the paper assumes exists at the edge: the thing that
 actually executes a cached GenAI model for a user request.
@@ -27,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.mla_decode import rows_read
 from repro.models.lm import (LMCfg, lm_decode, lm_init_cache, lm_prefill)
 from repro.nn.moe import held_load, held_range
 
@@ -53,12 +55,13 @@ class _Slot:
     generated: Optional[list] = None
 
 
-def _moe_cfg(cfg: LMCfg):
-    """The MoE configuration of ``cfg``'s expert layers, or None."""
+def _layer_cfg(cfg: LMCfg, part: str, kind: str):
+    """The configuration of ``cfg``'s first block whose ``part`` ("ffn" or
+    "mixer") is ``kind`` ("moe", "mla"), or None."""
     for g in cfg.groups:
         for b in g.cycle:
-            if b.ffn == "moe":
-                return b.moe
+            if getattr(b, part) == kind:
+                return getattr(b, kind)
     return None
 
 
@@ -72,7 +75,8 @@ class Engine:
         self.pos = np.zeros(B, np.int32)          # next position per slot
         self.slots: List[_Slot] = [_Slot() for _ in range(B)]
         self.last_tok = np.zeros(B, np.int32)
-        self.moe = _moe_cfg(cfg)
+        self.moe = _layer_cfg(cfg, "ffn", "moe")
+        self.mla = _layer_cfg(cfg, "mixer", "mla")
         self.n_moe_layers = sum(g.repeats * sum(b.ffn == "moe" for b in g.cycle)
                                 for g in cfg.groups)
         # device arrays of the latest call: its logits, and the experts each
@@ -128,7 +132,7 @@ class Engine:
     @staticmethod
     def _zero_counts() -> dict:
         return {"prefills": 0, "prefill_tokens": 0, "decode_steps": 0,
-                "decode_tokens": 0, "live_tokens": 0}
+                "decode_tokens": 0, "live_tokens": 0, "cache_rows_read": 0}
 
     def _zero_stats(self) -> dict:
         if self.moe is None:
@@ -143,13 +147,15 @@ class Engine:
         """Counts since the engine started (or its warm-up ended): prefills
         and their prompt tokens; decode steps, the tokens they decoded
         (active slots summed over steps) and the cache positions those
-        tokens attended (``live_tokens``).  With MoE layers also, from the
-        device: ``expert_picks`` (picks of held experts by decoded tokens,
-        over every MoE layer), ``expert_picks_max`` (the most one held
-        expert of one layer took in one step), ``experts_touched`` (held
-        experts, over layers and steps, that a step ran for at least one
-        pick), ``prefill_expert_picks`` and ``dropped`` (held picks left
-        uncomputed, prefill and decode)."""
+        tokens attended (``live_tokens``), and the cache rows the MLA decode
+        kernel fetched for them in one layer, in whole blocks
+        (``cache_rows_read``; 0 without MLA layers).  With MoE layers also,
+        from the device: ``expert_picks`` (picks of held experts by decoded
+        tokens, over every MoE layer), ``expert_picks_max`` (the most one
+        held expert of one layer took in one step), ``experts_touched``
+        (held experts, over layers and steps, that a step ran for at least
+        one pick), ``prefill_expert_picks`` and ``dropped`` (held picks
+        left uncomputed, prefill and decode)."""
         out = dict(self._counts)
         if self._stats:
             st = jax.device_get(self._stats)
@@ -199,7 +205,10 @@ class Engine:
         return [i for i, s in enumerate(self.slots) if s.uid is not None]
 
     def _run_decode(self, active):
-        state = np.stack([self.last_tok, self.pos, active.astype(np.int32)])
+        # a free slot decodes at position 0, so attention reads none of its
+        # rows; its output is dropped, and a prefill rewrites its cache
+        state = np.stack([self.last_tok, np.where(active, self.pos, 0),
+                          active.astype(np.int32)])
         nxt, self.logits, self.route, self.cache, self._stats = self._decode(
             self.params, state, self.cache, self._stats)
         return nxt
@@ -212,6 +221,9 @@ class Engine:
         self._counts["decode_steps"] += 1
         self._counts["decode_tokens"] += int(active.sum())
         self._counts["live_tokens"] += int((self.pos[active] + 1).sum())
+        if self.mla is not None:
+            self._counts["cache_rows_read"] += rows_read(
+                self.pos[active], self.sc.max_seq, self.mla.window)
         finished = []
         for i, s in enumerate(self.slots):
             if s.uid is None:

@@ -89,6 +89,29 @@ def test_engine_decodes_from_true_length(served):
     assert c["expert_picks"] == held
 
 
+def test_cache_rows_read_counts_whole_live_blocks():
+    """``cache_rows_read`` is the hand count of the rows the decode kernel
+    fetched, each slot's live rows rounded up to whole blocks of 16 (a
+    48-position cache), over steps with staggered admissions; the decode
+    step compiles once though every step's positions differ."""
+    lm = get_arch("deepseek-v2-lite").make_smoke(held=(2, 4))
+    eng = Engine(lm, lm_init(KEY, lm), ServeCfg(max_batch=3, max_seq=48))
+    rng = np.random.default_rng(2)
+    prompt = lambda L: rng.integers(0, lm.vocab, L).astype(np.int32)
+    eng.admit(0, prompt(14), 8)
+    for step in range(6):
+        if step == 2:
+            eng.admit(1, prompt(3), 8)
+        if step == 4:
+            eng.admit(2, prompt(17), 8)
+        eng.step()
+    # slot 0 at 14..19: 1, 1, 1, 2, 2, 2 blocks; slot 1 at 3..6: 1 block
+    # each; slot 2 at 17, 18: 2 blocks each
+    assert eng.counters()["cache_rows_read"] == 16 * (3 + 6) + 16 * 4 + 32 * 2
+    assert eng.counters()["decode_steps"] == 6
+    assert eng._decode._cache_size() == 1
+
+
 def _layer(n_experts=8, top_k=3, held=None, norm=False, d=32, f=16, seed=0):
     cfg = MoECfg(d, f, n_experts=n_experts, top_k=top_k, n_shared=2,
                  norm_topk_prob=norm, held=held)

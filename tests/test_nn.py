@@ -1,5 +1,10 @@
 """Unit tests for the nn substrate: norms, rope, attention (incl. decode
 consistency), MLA absorbed-decode equivalence, MoE dispatch, SSD."""
+import os
+import subprocess
+import sys
+import textwrap
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -86,11 +91,74 @@ def test_mla_absorbed_decode_matches_expanded_forward():
     cache = init_mla_cache(2, 12, cfg, jnp.float32)
     y = None
     for t in range(10):
-        y, row = mla_decode(p, cfg, x[:, t:t + 1], cache, jnp.int32(t),
-                            **F32)
+        # the decode reads a cache stacked over layers, here of one layer
+        y, row = mla_decode(p, cfg, x[:, t:t + 1],
+                            jax.tree.map(lambda c: c[None], cache),
+                            jnp.int32(t), layer=0, **F32)
         cache = _write_row(cache, row, t)
     np.testing.assert_allclose(np.asarray(y[:, 0]),
                                np.asarray(full[:, -1]), rtol=2e-4, atol=2e-4)
+
+
+_MLA_MESH_SCRIPT = textwrap.dedent(r"""
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.roofline import collective_bytes
+    from repro.nn import sharding as shlib
+    from repro.nn.mla import (MLACfg, init_mla_cache, mla_cache_spec,
+                              mla_decode, mla_init)
+    from repro.nn.sharding import fit_spec
+
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         (jax.sharding.AxisType.Auto,) * 2)
+    key = jax.random.PRNGKey(3)
+    for window in (None, 20):
+        cfg = MLACfg(64, 4, q_lora_rank=32, kv_lora_rank=32, qk_nope_dim=16,
+                     qk_rope_dim=8, v_head_dim=16, window=window)
+        p = mla_init(key, cfg)
+        B, S, R = 4, 64, 2
+        x = jax.random.normal(key, (B, 1, 64))
+        kv = jax.random.normal(key, (R,) + init_mla_cache(B, S, cfg)["kv"].shape)
+        # before, inside and past the second shard's rows (32..63)
+        pos = jnp.array([5, 31, 40, 63], jnp.int32)
+        f = lambda p, x, kv, pos: mla_decode(p, cfg, x, {"kv": kv}, pos,
+                                             layer=1, compute_dtype=jnp.float32)
+        want, _ = jax.jit(f)(p, x, kv, pos)
+        with shlib.use_mesh(mesh), mesh:
+            spec = fit_spec(P(None, *mla_cache_spec(cfg)["kv"]), kv.shape, mesh)
+            assert spec == P(None, "data", "model", None), spec
+            kv_sh = jax.device_put(kv, NamedSharding(mesh, spec))
+            step = jax.jit(f)
+            got, _ = step(p, x, kv_sh, pos)
+            text = step.lower(p, x, kv_sh, pos).compile().as_text()
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-4)
+        # a device holds 1/4 of the cache: no collective moves its rows
+        shard = kv.size * kv.dtype.itemsize // 4
+        gathered = collective_bytes(text)["all-gather"]
+        assert gathered < shard // 8, (gathered, shard)
+    print("MLA-MESH-OK")
+""")
+
+
+def test_mla_decode_on_a_mesh_splits_the_cache_sequence():
+    """On a 2x2 (data, model) mesh of forced host devices, with the cache
+    laid out by ``mla_cache_spec`` (slots over "data", sequence over
+    "model"), the decode kernel runs on each device's shard and the
+    shards' parts combine to the single-device result, windowed or not,
+    with positions before, inside and past a shard; no collective gathers
+    the cache.  Runs in a subprocess: the device count must be set before
+    jax initializes."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=4").strip()
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src"),
+         env.get("PYTHONPATH", "")]).rstrip(os.pathsep)
+    out = subprocess.run([sys.executable, "-c", _MLA_MESH_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, f"stdout:\n{out.stdout}\nstderr:\n{out.stderr}"
+    assert "MLA-MESH-OK" in out.stdout
 
 
 def test_mla_cache_is_compressed():

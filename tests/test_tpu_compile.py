@@ -14,7 +14,8 @@ V5E_HBM_BYTES = 16 * 1024 ** 3
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def four_chips():
+    """The four described chips of a v5e 2x2 host."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     topo = topologies.get_topology_desc(platform="tpu",
@@ -24,9 +25,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(four_chips):
+    return SingleDeviceSharding(four_chips[0])
 
 
 def _shape(one_chip, shape, dtype=jnp.float32):
@@ -68,6 +74,92 @@ def test_ssd_scan_mamba2_130m(one_chip):
         _shape(one_chip, (H,)), _shape(one_chip, (B, G, L, N)),
         _shape(one_chip, (B, G, L, N)), _shape(one_chip, (H,))).compile()
     _assert_kernel(compiled)
+
+
+def test_mla_decode_deepseek_v2_lite(one_chip):
+    from repro.kernels.mla_decode import mla_decode_bhw
+    # 64 slots, 2048 positions, rows of 576 values padded to 640 lanes,
+    # 26 stacked MoE layers, 16 heads
+    B, H, W, S, R = 64, 16, 640, 2048, 26
+    fn = jax.jit(lambda *a: mla_decode_bhw(*a, scale=0.1, width=512))
+    _assert_kernel(fn.lower(
+        _shape(one_chip, (B, H, W), jnp.bfloat16),
+        _shape(one_chip, (B, 1, W), jnp.bfloat16),
+        _shape(one_chip, (R, B, S, W), jnp.bfloat16),
+        _shape(one_chip, (B,), jnp.int32),
+        _shape(one_chip, (1,), jnp.int32)).compile())
+
+
+def test_deepseek_v2_lite_decode_step_reads_the_cache_in_place(one_chip,
+                                                               monkeypatch):
+    """The gateway's decode step at the cell's size: the kernel reads each
+    layer in the donated stacked cache, so no layer (168 MB) or cache
+    (4.5 GB) is copied: the step's temporaries stay at MB scale."""
+    from repro.configs import get_arch
+    from repro.kernels import ops
+    from repro.models.lm import lm_decode, lm_init, lm_init_cache
+    monkeypatch.setattr(ops, "_interpret", lambda: False)  # the CPU's branch
+    cfg = get_arch("deepseek-v2-lite").make_full(held=(0, 16))
+    B, S = 64, 2048
+    place = lambda t: jax.tree.map(
+        lambda a: _shape(one_chip, a.shape, a.dtype), t)
+    params = place(jax.eval_shape(
+        lambda k: lm_init(k, cfg, dtype=jnp.bfloat16),
+        jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    cache = place(jax.eval_shape(lambda: lm_init_cache(cfg, B, S)))
+
+    def step(params, tok, pos, cache):
+        logits, cache = lm_decode(params, cfg, tok[:, None], cache, pos)
+        return jnp.argmax(logits[:, 0], -1), cache
+
+    ids = _shape(one_chip, (B,), jnp.int32)
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        params, ids, ids, cache).compile()
+    _assert_kernel(compiled)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * 1024 ** 3, mem
+    assert mem.temp_size_in_bytes < 16 * 1024 ** 2, mem
+
+
+def test_mla_decode_deepseek_v3_splits_the_cache_over_chips(four_chips,
+                                                          monkeypatch):
+    """DeepSeek-V3's decode attention on a 2x2 (data, model) mesh of
+    described chips, the cache laid out by ``mla_cache_spec``: slots over
+    "data", sequence over "model".  Each chip runs the kernel's split form
+    on its rows, and no collective gathers the cache (a chip's shard is
+    3 x 8 x 2048 rows of 640 bf16, 63 MB)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.configs import get_arch
+    from repro.kernels import ops
+    from repro.launch.roofline import collective_bytes
+    from repro.nn import sharding as shlib
+    from repro.nn.mla import (cache_row_width, mla_cache_spec, mla_decode,
+                              mla_init)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)  # the CPU's branch
+    cfg = get_arch("deepseek-v3-671b").make_full().groups[0].cycle[0].mla
+    B, S, R = 16, 4096, 3
+    mesh = Mesh(np.array(four_chips).reshape(2, 2), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    on = lambda spec, a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=NamedSharding(mesh, spec))
+    params = jax.tree.map(lambda a: on(P(), a), jax.eval_shape(
+        lambda k: mla_init(k, cfg, dtype=jnp.bfloat16),
+        jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    with shlib.use_mesh(mesh), mesh:
+        kv = on(P(None, *mla_cache_spec(cfg)["kv"]), jax.ShapeDtypeStruct(
+            (R, B, S, cache_row_width(cfg)), jnp.bfloat16))
+        x = on(P("data"), jax.ShapeDtypeStruct((B, 1, cfg.d_model),
+                                               jnp.bfloat16))
+        pos = on(P("data"), jax.ShapeDtypeStruct((B,), jnp.int32))
+        compiled = jax.jit(
+            lambda p, x, kv, pos: mla_decode(p, cfg, x, {"kv": kv}, pos,
+                                             layer=1)[0]
+        ).lower(params, x, kv, pos).compile()
+    _assert_kernel(compiled)
+    shard = R * (B // 2) * (S // 2) * cache_row_width(cfg) * 2
+    moved = collective_bytes(compiled.as_text())
+    assert moved["all-gather"] < shard // 64, (moved, shard)
 
 
 def test_fused_training_program_fits_one_chip(one_chip):
